@@ -21,7 +21,10 @@ from repro.core.strategies import StrategyLike
 from repro.data import datasets as ds
 from repro.federated.api import Experiment
 from repro.federated.engine import resolve_engine
+from repro.launch import compile_cache
 from repro.models.config import FederatedConfig
+
+compile_cache.enable()
 
 QUICK = os.environ.get("BENCH_QUICK", "1") != "0"
 ENGINE = os.environ.get("BENCH_ENGINE", "sim")

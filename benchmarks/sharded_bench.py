@@ -28,8 +28,6 @@ import os
 import subprocess
 import sys
 
-import jax
-
 from benchmarks.common import QUICK as _ENV_QUICK, emit, row
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -120,7 +118,8 @@ base = finals[CHUNKS[0]]
 for r in rows:
     r["bit_equal_to_k1"] = bool(np.array_equal(base, finals[r["rounds_per_call"]]))
 
-print("RESULT " + json.dumps(rows))
+print("RESULT " + json.dumps({"backend": jax.default_backend(),
+                              "rows": rows}))
 """
 
 
@@ -136,7 +135,8 @@ def sharded_sweep(rows):
     assert proc.returncode == 0, proc.stderr[-4000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")]
     assert line, proc.stdout
-    jrows = json.loads(line[0][len("RESULT "):])
+    result = json.loads(line[0][len("RESULT "):])
+    jrows = result["rows"]
     for cell in jrows:
         k = cell["rounds_per_call"]
         rows.append(row("sharded", f"rpc{k}", "rounds_per_s",
@@ -161,13 +161,13 @@ def sharded_sweep(rows):
                     summary["dispatch_savings"]))
     rows.append(row("sharded", "summary", "dispatch_reduction",
                     summary["dispatch_reduction"]))
-    return jrows, summary
+    return jrows, summary, result["backend"]
 
 
-def write_bench_json(jrows, summary):
+def write_bench_json(jrows, summary, backend):
     payload = {
         "bench": "sharded_rounds_per_call_scan",
-        "backend": jax.default_backend(),
+        "backend": backend,
         "devices_forced": 8,
         "note": ("rounds/s are CPU container figures over a forced "
                  "8-host-device (data=4, model=2) mesh with FSDP backbone "
@@ -186,9 +186,9 @@ def write_bench_json(jrows, summary):
 
 def main():
     rows = []
-    jrows, summary = sharded_sweep(rows)
+    jrows, summary, backend = sharded_sweep(rows)
     assert summary["all_bit_equal"], jrows
-    write_bench_json(jrows, summary)
+    write_bench_json(jrows, summary, backend)
     return emit(rows, "Sharded engine (2-D mesh rounds_per_call scan)")
 
 

@@ -26,7 +26,7 @@ VIT_TINY = ModelConfig(
 )
 
 GPT_TINY = ModelConfig(
-    name="gpt-tiny", family="dense", num_layers=2, d_model=64,
+    name="gpt2-tiny", family="dense", num_layers=2, d_model=64,
     num_heads=4, num_kv_heads=4, d_ff=128, vocab_size=256,
     activation="gelu", use_learned_pos=True, max_seq=256,
     param_dtype="float32", compute_dtype="float32",

@@ -17,6 +17,7 @@ _MODULES = {
     "deepseek-v3-671b": "repro.configs.deepseek_v3_671b",
     "hymba-1.5b": "repro.configs.hymba_1_5b",
     "qwen3-32b": "repro.configs.qwen3_32b",
+    "gpt2-small": "repro.configs.gpt2_small",
 }
 
 ARCH_IDS = tuple(_MODULES)

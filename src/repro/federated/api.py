@@ -184,7 +184,7 @@ class Experiment:
                   fsdp: bool = False, rounds_per_call: int = 1,
                   donate: bool = True) -> "Experiment":
         """2-D client-axis × model-axis ShardedEngine: `shape=(c, m)`
-        builds `jax.make_mesh((c, m), ("data", "model"))` — the vmapped
+        builds `launch.mesh.make_train_mesh(c, m)` (Auto axes) — the vmapped
         client dimension shards over "data" while the backbone params
         (which `run()` passes as an explicit step argument) TP-shard over
         "model"; `fsdp=True` overlays ZeRO-3 so weight storage dims shard

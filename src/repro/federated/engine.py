@@ -729,7 +729,8 @@ class ShardedEngine(Engine):
     @property
     def mesh(self):
         if self._mesh is None:
-            self._mesh = jax.make_mesh((1, 1), ("data", "model"))
+            from repro.launch.mesh import make_train_mesh
+            self._mesh = make_train_mesh(1, 1)
         return self._mesh
 
     @property

@@ -29,10 +29,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bkv, T, scale, causal):
 
     def body(j, carry):
         m, l, acc = carry
-        # index the leading (size-1) block dim with a length-1 slice: raw int
-        # indices break interpret-mode discharge on current jax (API drift)
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(j * bkv, bkv), slice(None)))[0]
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(j * bkv, bkv), slice(None)))[0]
+        k = k_ref[0, pl.ds(j * bkv, bkv), :]
+        v = v_ref[0, pl.ds(j * bkv, bkv), :]
         k = k.astype(jnp.float32)
         v = v.astype(jnp.float32)
         s = q @ k.T                                     # (bq, bkv)

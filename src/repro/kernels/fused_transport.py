@@ -26,7 +26,7 @@ path to **three** streaming passes over the flat delta:
                                — mask at the threshold, quantize the
                                  survivors (same float ops as
                                  `quantization.quantize`), count the
-                                 nnz, and (pack variant) scatter the
+                                 nnz, and (pack variant) write the
                                  coded wire form — ascending indices +
                                  values — into a static-capacity buffer,
                                  all in one kernel.
@@ -51,9 +51,14 @@ host-transfer coding).
 
 Backend notes: like `kernels/topk_mask.py`, these kernels run natively on
 TPU and under Pallas interpret mode everywhere else (the selector layer
-owns that dispatch).  The in-kernel bincount/scatter lower through jnp
-`.at[]` ops; the TPU-native lowering is re-baselined with the rest of
-`BENCH_topk.json` on a real TPU host (open ROADMAP item).  The
+owns that dispatch).  The TPU compiler takes no scatter and no block
+below the (8, 128) tiling, so every stream is viewed as (rows, 128)
+tiles, scalars (threshold, scale, hi0, the running pack offset) sit in
+SMEM, and per-block reductions leave as (1, 128) partials.  The bin
+histogram is an MXU product of one-hot high and low bin bits; the pack
+compacts each tile row with a lane-shift network and writes the rows at
+the running offset (`_pack_tile`).  tests/test_tpu_compile.py compiles
+every kernel for a described v5e chip at GPT-2 Small's sizes.  The
 single-vector pack variant accumulates its packed outputs across the
 sequential grid via `pl.program_id(0)`, so it must not be vmapped;
 batched callers (the engines' cohort pack step) use
@@ -69,8 +74,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_mask import BLOCK
+from repro.kernels.topk_mask import (BLOCK, LANES, SMEM, check_stream,
+                                     partial_spec, scalars, stream_spec,
+                                     tiles)
 
 LEVELS = 12         # default bisection depth: 2^12 magnitude bins (16 KiB)
 
@@ -80,7 +88,7 @@ LEVELS = 12         # default bisection depth: 2^12 magnitude bins (16 KiB)
 # ---------------------------------------------------------------------------
 
 def _absmax_kernel(x_ref, out_ref):
-    out_ref[0] = jnp.max(jnp.abs(x_ref[...]))
+    out_ref[...] = jnp.max(jnp.abs(x_ref[...]), axis=0, keepdims=True)
 
 
 def absmax_pallas(x: jax.Array, *, block: int = BLOCK,
@@ -88,16 +96,16 @@ def absmax_pallas(x: jax.Array, *, block: int = BLOCK,
     """max |x| of a (n,) vector, n % block == 0 (pad upstream).  Bitwise
     equal to `jnp.max(jnp.abs(x))`: max-of-block-maxes is order-free."""
     n = x.shape[0]
-    assert n % block == 0, (n, block)
+    check_stream(n, block)
     grid = (n // block,)
     part = pl.pallas_call(
         _absmax_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((grid[0],), jnp.float32),
+        in_specs=[stream_spec(block)],
+        out_specs=partial_spec(),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 1, LANES), jnp.float32),
         interpret=interpret,
-    )(x.astype(jnp.float32))
+    )(tiles(x.astype(jnp.float32)))
     return jnp.max(part)
 
 
@@ -105,10 +113,10 @@ def absmax_pallas(x: jax.Array, *, block: int = BLOCK,
 # pass 2: bisection-path bin counts + the threshold replay
 # ---------------------------------------------------------------------------
 
-def _bin_kernel(levels, hi_ref, x_ref, hist_ref):
+def _bin_kernel(levels, s_ref, x_ref, hist_ref, idx_ref):
     a = jnp.abs(x_ref[...])
     lo = jnp.zeros_like(a)
-    hi = jnp.full_like(a, hi_ref[0])
+    hi = jnp.full_like(a, s_ref[0, 0])
     idx = jnp.zeros(a.shape, jnp.int32)
     for _ in range(levels):                 # static unroll: `levels` is small
         mid = 0.5 * (lo + hi)               # the canonical recurrence,
@@ -116,7 +124,27 @@ def _bin_kernel(levels, hi_ref, x_ref, hist_ref):
         idx = idx * 2 + up.astype(jnp.int32)
         lo = jnp.where(up, mid, lo)
         hi = jnp.where(up, hi, mid)
-    hist_ref[0, :] = jnp.zeros((1 << levels,), jnp.int32).at[idx].add(1)
+    idx_ref[...] = idx
+    # bincount without a scatter: bin = (h, l) splits into its high and
+    # low bits, and hist[h, l] = sum over elements of onehot_h * onehot_l —
+    # one (nh, 128) x (nl, 128)^T MXU product per tile row.  The one-hots
+    # are exact in bf16 and a row's counts (<= 128) in f32; rows add up
+    # in int32.
+    nh, nl = hist_ref.shape
+    lo_bits = nl.bit_length() - 1
+    hs = jax.lax.broadcasted_iota(jnp.int32, (nh, LANES), 0)
+    ls = jax.lax.broadcasted_iota(jnp.int32, (nl, LANES), 0)
+
+    def row(r, acc):
+        v = idx_ref[pl.ds(r, 1), :]
+        oh = jnp.where((v >> lo_bits) == hs, 1.0, 0.0).astype(jnp.bfloat16)
+        ol = jnp.where((v & (nl - 1)) == ls, 1.0, 0.0).astype(jnp.bfloat16)
+        return acc + jax.lax.dot_general(
+            oh, ol, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    hist_ref[...] = jax.lax.fori_loop(0, a.shape[0], row,
+                                      jnp.zeros((nh, nl), jnp.int32))
 
 
 def bin_counts_pallas(x: jax.Array, hi0: jax.Array, levels: int = LEVELS,
@@ -125,21 +153,20 @@ def bin_counts_pallas(x: jax.Array, hi0: jax.Array, levels: int = LEVELS,
     """(2^levels,) int32 histogram of bisection-path bin indices for a
     (n,) vector with n % block == 0.  `hi0` is the absmax from pass 1."""
     n = x.shape[0]
-    assert n % block == 0, (n, block)
+    check_stream(n, block)
     grid = (n // block,)
-    bins = 1 << levels
+    nl = 1 << (levels // 2)
+    nh = 1 << (levels - levels // 2)
     hist = pl.pallas_call(
         functools.partial(_bin_kernel, levels),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),            # hi0 (broadcast)
-            pl.BlockSpec((block,), lambda i: (i,)),        # x tile
-        ],
-        out_specs=pl.BlockSpec((1, bins), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], bins), jnp.int32),
+        in_specs=[SMEM, stream_spec(block)],
+        out_specs=pl.BlockSpec((None, nh, nl), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], nh, nl), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((block // LANES, LANES), jnp.int32)],
         interpret=interpret,
-    )(jnp.reshape(hi0.astype(jnp.float32), (1,)), x.astype(jnp.float32))
-    return jnp.sum(hist, axis=0)
+    )(scalars(hi0), tiles(x.astype(jnp.float32)))
+    return jnp.sum(hist, axis=0).reshape(nh * nl)
 
 
 def threshold_from_bins(hist: jax.Array, hi0: jax.Array, k,
@@ -188,14 +215,19 @@ def _quantized(x, u, bits: int, stochastic: bool, scale):
     return jnp.clip(y, -qmax - 1.0, qmax) * scale
 
 
-def _fuse_kernel(bits, stochastic, s_ref, x_ref, u_ref, out_ref, cnt_ref):
-    t = s_ref[0]
-    scale = s_ref[1]
+def _mask_quantize(bits, stochastic, s_ref, x_ref, u_ref):
+    """(kept mask, masked+quantized tile) at threshold s[0], scale s[1]."""
     x = x_ref[...]
-    keep = jnp.abs(x) >= t
-    q = _quantized(x, u_ref[...], bits, stochastic, scale) if bits else x
-    out_ref[...] = jnp.where(keep, q, jnp.zeros_like(q))
-    cnt_ref[0] = jnp.sum(keep.astype(jnp.int32))
+    keep = jnp.abs(x) >= s_ref[0, 0]
+    q = _quantized(x, u_ref[...], bits, stochastic, s_ref[0, 1]) \
+        if bits else x
+    return keep, jnp.where(keep, q, jnp.zeros_like(q))
+
+
+def _fuse_kernel(bits, stochastic, s_ref, x_ref, u_ref, out_ref, cnt_ref):
+    keep, out = _mask_quantize(bits, stochastic, s_ref, x_ref, u_ref)
+    out_ref[...] = out
+    cnt_ref[...] = jnp.sum(keep.astype(jnp.int32), axis=0, keepdims=True)
 
 
 def fused_mask_quantize_pallas(x: jax.Array, threshold: jax.Array,
@@ -208,62 +240,135 @@ def fused_mask_quantize_pallas(x: jax.Array, threshold: jax.Array,
     `quantization.quantize` bit-for-bit, then padded.  bits == 0 skips
     quantization (plain mask + count).  vmap-safe."""
     n = x.shape[0]
-    assert n % block == 0, (n, block)
+    check_stream(n, block)
     grid = (n // block,)
     stochastic = u is not None
-    s = jnp.stack([threshold.astype(jnp.float32),
-                   scale.astype(jnp.float32)])
     uu = x if u is None else u              # placeholder keeps specs static
     masked, counts = pl.pallas_call(
         functools.partial(_fuse_kernel, bits, stochastic),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),            # thr, scale
-            pl.BlockSpec((block,), lambda i: (i,)),        # x tile
-            pl.BlockSpec((block,), lambda i: (i,)),        # uniform tile
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
+        in_specs=[SMEM, stream_spec(block), stream_spec(block)],
+        out_specs=[stream_spec(block), partial_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.int32),
+            jax.ShapeDtypeStruct((n // LANES, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(s, x.astype(jnp.float32), uu.astype(jnp.float32))
-    return masked, jnp.sum(counts)
+    )(scalars(threshold, scale), tiles(x.astype(jnp.float32)),
+      tiles(uu.astype(jnp.float32)))
+    return masked.reshape(n), jnp.sum(counts)
 
 
-def _fuse_pack_kernel(bits, stochastic, cap, sentinel,
+# ---------------------------------------------------------------------------
+# in-kernel packing without a scatter
+# ---------------------------------------------------------------------------
+
+def _lane_shift_left(x, s):
+    """x[:, j + s] at lane j (lanes past the end wrap; callers mask)."""
+    return pltpu.roll(x, LANES - s, 1)
+
+
+def _pack_tile(keep, vals, base, off, cval_ref, cidx_ref, ccnt_ref,
+               idx_acc, val_acc):
+    """Append the kept entries of one (rows, LANES) tile, in row-major
+    order, to the packed accumulators at slot `off`; returns the new
+    offset.  Mosaic has no scatter, so the pack runs in two steps:
+
+    1. every row is compacted in place by a shift network: a kept entry
+       moves left by the number of dropped entries before it in its row,
+       one power of two per stage.  Shift distances never decrease
+       along a row, so applying their bits from the lowest up never
+       lands two entries on one lane;
+    2. each compacted row is rotated to the running slot offset and
+       written into (at most) two accumulator rows under a lane mask.
+
+    Entries and indices only move and are selected, so values are
+    bit-exact.  Slots at or past the accumulator's last full row are
+    dropped; the caller slices the `cap` slots it owns."""
+    rows = keep.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
+    src = base + jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0) * LANES \
+        + lane
+    alive = keep.astype(jnp.int32)
+    pre = alive                             # inclusive prefix count per row
+    for k in range(LANES.bit_length() - 1):
+        s = 1 << k
+        pre = pre + jnp.where(lane >= s, pltpu.roll(pre, s, 1), 0)
+    dist = jnp.where(keep, lane + 1 - pre, 0)
+    for k in range(LANES.bit_length() - 1):
+        s = 1 << k
+        move = alive * ((dist >> k) & 1)
+        came = _lane_shift_left(move, s) == 1
+        alive = jnp.where(came, 1, alive - move)
+        vals = jnp.where(came, _lane_shift_left(vals, s), vals)
+        src = jnp.where(came, _lane_shift_left(src, s), src)
+        dist = jnp.where(came, _lane_shift_left(dist, s), dist)
+    cval_ref[...] = vals
+    cidx_ref[...] = src
+    ccnt_ref[...] = pre
+    full_rows = idx_acc.shape[0] - 1
+    lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def place(r, off):
+        c = jnp.max(ccnt_ref[pl.ds(r, 1), :])
+        sh = off % LANES
+        q = off // LANES
+        head = (lane1 >= sh) & (lane1 < sh + c)
+        tail = lane1 < sh + c - LANES
+
+        @pl.when(q < full_rows)
+        def _():
+            for ref, row in ((val_acc, cval_ref), (idx_acc, cidx_ref)):
+                v = pltpu.roll(row[pl.ds(r, 1), :], sh, 1)
+                ref[pl.ds(q, 1), :] = jnp.where(head, v, ref[pl.ds(q, 1), :])
+                ref[pl.ds(q + 1, 1), :] = jnp.where(
+                    tail, v, ref[pl.ds(q + 1, 1), :])
+        return off + c
+
+    return jax.lax.fori_loop(0, rows, place, off)
+
+
+def _acc_rows(cap: int) -> int:
+    """Accumulator rows for `cap` slots: whole rows plus one spill row."""
+    return -(-cap // LANES) + 1
+
+
+def _pack_scratch(block: int):
+    rows = block // LANES
+    return [pltpu.VMEM((rows, LANES), jnp.float32),
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32)]
+
+
+def _pack_params(block: int, cap: int, semantics):
+    """VMEM budget: the resident accumulators (double-buffered) plus the
+    streamed tiles and the compaction scratch, with headroom."""
+    acc = 2 * 2 * _acc_rows(cap) * LANES * 4
+    tiles_b = 8 * block * 4
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=max(32 << 20, int(1.25 * (acc + tiles_b))))
+
+
+def _fuse_pack_kernel(bits, stochastic, sentinel,
                       s_ref, x_ref, u_ref,
-                      out_ref, idx_ref, val_ref, tot_ref):
+                      out_ref, idx_ref, val_ref, tot_ref,
+                      cval_ref, cidx_ref, ccnt_ref, off_ref):
     i = pl.program_id(0)
-    t = s_ref[0]
-    scale = s_ref[1]
-    x = x_ref[...]
-    block = x.shape[-1]
-    keep = jnp.abs(x) >= t
-    q = _quantized(x, u_ref[...], bits, stochastic, scale) if bits else x
-    out = jnp.where(keep, q, jnp.zeros_like(q))
+    keep, out = _mask_quantize(bits, stochastic, s_ref, x_ref, u_ref)
     out_ref[...] = out
 
     @pl.when(i == 0)
     def _init():
-        idx_ref[...] = jnp.full((cap,), sentinel, jnp.int32)
-        val_ref[...] = jnp.zeros((cap,), jnp.float32)
-        tot_ref[0] = 0
+        idx_ref[...] = jnp.full(idx_ref.shape, sentinel, jnp.int32)
+        val_ref[...] = jnp.zeros(val_ref.shape, jnp.float32)
+        off_ref[0] = 0
 
-    # pack the block's survivors at the running global offset; position
-    # `cap` (non-kept) and positions past `cap` (overflow) scatter-drop,
-    # so `tot` > cap flags overflow without ever corrupting the buffer
-    off = tot_ref[0]
-    kept = keep.astype(jnp.int32)
-    pos = jnp.where(keep, off + jnp.cumsum(kept) - 1, cap)
-    src = i * block + jax.lax.iota(jnp.int32, block)
-    idx_ref[...] = idx_ref[...].at[pos].set(src, mode="drop")
-    val_ref[...] = val_ref[...].at[pos].set(out, mode="drop")
-    tot_ref[0] = off + jnp.sum(kept)
+    off = _pack_tile(keep, out, i * keep.size, off_ref[0], cval_ref,
+                     cidx_ref, ccnt_ref, idx_ref, val_ref)
+    off_ref[0] = off
+    tot_ref[...] = jnp.full(tot_ref.shape, off, jnp.int32)
 
 
 def fused_mask_quantize_pack_pallas(x: jax.Array, threshold: jax.Array,
@@ -280,37 +385,33 @@ def fused_mask_quantize_pack_pallas(x: jax.Array, threshold: jax.Array,
     holds the first `cap` survivors and the caller must fall back to the
     dense form.  Accumulates across the sequential grid (pl.program_id),
     so NOT vmap-safe — batch callers use the non-pack variant +
-    `pack_values`."""
+    `pack_values_batch`."""
     n = x.shape[0]
-    assert n % block == 0, (n, block)
+    check_stream(n, block)
     grid = (n // block,)
     stochastic = u is not None
-    s = jnp.stack([threshold.astype(jnp.float32),
-                   scale.astype(jnp.float32)])
     uu = x if u is None else u
+    rows = _acc_rows(cap)
+    acc = pl.BlockSpec((rows, LANES), lambda i: (0, 0))
     masked, idx, val, tot = pl.pallas_call(
-        functools.partial(_fuse_pack_kernel, bits, stochastic, cap, sentinel),
+        functools.partial(_fuse_pack_kernel, bits, stochastic, sentinel),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((cap,), lambda i: (0,)),          # accumulated
-            pl.BlockSpec((cap,), lambda i: (0,)),          # accumulated
-            pl.BlockSpec((1,), lambda i: (0,)),            # running offset
-        ],
+        in_specs=[SMEM, stream_spec(block), stream_spec(block)],
+        out_specs=[stream_spec(block), acc, acc,
+                   pl.BlockSpec((1, LANES), lambda i: (0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((cap,), jnp.int32),
-            jax.ShapeDtypeStruct((cap,), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
+            jax.ShapeDtypeStruct((n // LANES, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((1, LANES), jnp.int32),
         ],
+        scratch_shapes=_pack_scratch(block),
+        compiler_params=_pack_params(block, cap, ("arbitrary",)),
         interpret=interpret,
-    )(s, x.astype(jnp.float32), uu.astype(jnp.float32))
-    return masked, idx, val, tot[0]
+    )(scalars(threshold, scale), tiles(x.astype(jnp.float32)),
+      tiles(uu.astype(jnp.float32)))
+    return (masked.reshape(n), idx.reshape(-1)[:cap], val.reshape(-1)[:cap],
+            tot[0, 0])
 
 
 # ---------------------------------------------------------------------------
@@ -334,32 +435,24 @@ def pack_values(values: jax.Array, cap: int, mask=None):
     return idx, val, jnp.sum(kept)
 
 
-def _pack_batch_kernel(cap, sentinel, x_ref, idx_ref, val_ref, tot_ref):
+def _pack_batch_kernel(sentinel, x_ref, idx_ref, val_ref, tot_ref,
+                       cval_ref, cidx_ref, ccnt_ref, off_ref):
     """Batched pack: grid (B, nblocks); the per-row accumulator blocks are
-    indexed by the *batch* grid axis, so rows never share state (the
-    vmap-safety the single-vector `_fuse_pack_kernel` lacks) and the row
-    offset re-initializes at each row's first block."""
+    indexed by the *batch* grid axis, so rows never share state and the
+    row offset re-initializes at each row's first block."""
     j = pl.program_id(1)
-    x = x_ref[0, :]
-    block = x.shape[-1]
-    keep = x != 0
+    x = x_ref[...]
 
     @pl.when(j == 0)
     def _init():
-        idx_ref[...] = jnp.full((1, cap), sentinel, jnp.int32)
-        val_ref[...] = jnp.zeros((1, cap), jnp.float32)
-        tot_ref[0, 0] = 0
+        idx_ref[...] = jnp.full(idx_ref.shape, sentinel, jnp.int32)
+        val_ref[...] = jnp.zeros(val_ref.shape, jnp.float32)
+        off_ref[0] = 0
 
-    # same packing scheme as `_fuse_pack_kernel`: survivors land at the
-    # row's running offset, position `cap` (non-kept) and past-`cap`
-    # (overflow) scatter-drop, so tot > cap flags overflow uncorrupted
-    off = tot_ref[0, 0]
-    kept = keep.astype(jnp.int32)
-    pos = jnp.where(keep, off + jnp.cumsum(kept) - 1, cap)
-    src = j * block + jax.lax.iota(jnp.int32, block)
-    idx_ref[0, :] = idx_ref[0, :].at[pos].set(src, mode="drop")
-    val_ref[0, :] = val_ref[0, :].at[pos].set(x, mode="drop")
-    tot_ref[0, 0] = off + jnp.sum(kept)
+    off = _pack_tile(x != 0, x, j * x.size, off_ref[0], cval_ref, cidx_ref,
+                     ccnt_ref, idx_ref, val_ref)
+    off_ref[0] = off
+    tot_ref[...] = jnp.full(tot_ref.shape, off, jnp.int32)
 
 
 def pack_values_batched_pallas(values: jax.Array, cap: int, *,
@@ -370,27 +463,30 @@ def pack_values_batched_pallas(values: jax.Array, cap: int, *,
     index n — the *padded* length when the caller padded, which
     `pack_values_batch` clamps back to the unpadded length.  Otherwise
     bit-identical to `jax.vmap(lambda v: pack_values(v, cap))(values)`:
-    same keep mask, same cumsum positions, same overflow semantics."""
+    same keep mask, same ascending positions, same overflow semantics."""
     B, n = values.shape
-    assert n % block == 0, (n, block)
+    check_stream(n, block)
     grid = (B, n // block)
+    rows = _acc_rows(cap)
+    acc = pl.BlockSpec((None, rows, LANES), lambda b, j: (b, 0, 0))
     idx, val, tot = pl.pallas_call(
-        functools.partial(_pack_batch_kernel, cap, n),
+        functools.partial(_pack_batch_kernel, n),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, block), lambda b, j: (b, j))],
-        out_specs=[
-            pl.BlockSpec((1, cap), lambda b, j: (b, 0)),   # per-row accum
-            pl.BlockSpec((1, cap), lambda b, j: (b, 0)),   # per-row accum
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),     # per-row offset
-        ],
+        in_specs=[pl.BlockSpec((None, block // LANES, LANES),
+                               lambda b, j: (b, j, 0))],
+        out_specs=[acc, acc,
+                   pl.BlockSpec((None, 1, LANES), lambda b, j: (b, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((B, cap), jnp.int32),
-            jax.ShapeDtypeStruct((B, cap), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, rows, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, LANES), jnp.int32),
         ],
+        scratch_shapes=_pack_scratch(block),
+        compiler_params=_pack_params(block, cap, ("parallel", "arbitrary")),
         interpret=interpret,
-    )(values.astype(jnp.float32))
-    return idx, val, tot[:, 0]
+    )(tiles(values.astype(jnp.float32)))
+    return (idx.reshape(B, -1)[:, :cap], val.reshape(B, -1)[:, :cap],
+            tot[:, 0, 0])
 
 
 def pack_values_batch(values: jax.Array, cap: int, *,

@@ -8,6 +8,8 @@ cache report.
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b \
       --clients 8 --pages 4 --lanes 4 --requests 16
 
+`--full` serves the full-width config (e.g. `--arch gpt2-small --full`).
+
 The heavy lifting all lives in `repro.serving` (see docs/serving.md);
 this module only assembles the reduced architecture and the synthetic
 tenant population.
@@ -32,7 +34,13 @@ def main():
     ap.add_argument("--rank", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=48,
                     help="per-lane KV cache capacity (prompt + generation)")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the full-width config instead of the "
+                         "reduced smoke variant")
     args = ap.parse_args()
+
+    from repro.launch import compile_cache
+    cache_dir = compile_cache.enable()
 
     import jax
 
@@ -44,7 +52,10 @@ def main():
     from repro.serving import (HostAdapterStore, PagedAdapterCache,
                                ServingEngine, synth_trace)
 
-    cfg = get_config(args.arch, smoke=True)
+    cfg = get_config(args.arch, smoke=not args.full)
+    dev = jax.devices()[0]
+    print(f"[serve] device {dev.platform} ({dev.device_kind}) "
+          f"x{len(jax.devices())}, compile cache {cache_dir}")
     if cfg.encoder_decoder or cfg.embed_inputs or cfg.num_classes:
         raise SystemExit(f"[serve] {args.arch} is not a causal token LM; "
                          "the serving engine needs one")
@@ -67,7 +78,9 @@ def main():
     trace = synth_trace(args.requests, args.clients, cfg.vocab_size,
                         seed=args.seed, prompt_buckets=(8, 16),
                         gen_range=(4, 12))
-    print(f"[serve] {args.arch} (reduced: {cfg.num_layers}L d{cfg.d_model}) "
+    print(f"[serve] {args.arch} "
+          f"({'full' if args.full else 'reduced'}: "
+          f"{cfg.num_layers}L d{cfg.d_model}) "
           f"{args.clients} tenants / {args.pages} pages / {args.lanes} lanes")
     eng = ServingEngine(params, cfg, cache, n_lanes=args.lanes,
                         lora_scale=lcfg.scale, max_len=args.max_len)

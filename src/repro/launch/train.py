@@ -7,6 +7,9 @@ architecture — a thin CLI over `Experiment` + the engine registry.
   # scan-chunked dispatch (4 rounds per device call):
   PYTHONPATH=src python -m repro.launch.train --arch yi-9b --rounds-per-call 4
 
+  # the full-width config (GPT-2 Small fits one TPU v5e chip whole):
+  PYTHONPATH=src python -m repro.launch.train --arch gpt2-small --full
+
   # production lowering of the FULL config against the pod mesh (no compute):
   PYTHONPATH=src python -m repro.launch.train --arch yi-9b --dry-run [--multi-pod]
 
@@ -41,6 +44,9 @@ def main():
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0,
                     help="seeds param init and the synthetic data stream")
+    ap.add_argument("--full", action="store_true",
+                    help="train the full-width config instead of the "
+                         "reduced smoke variant")
     ap.add_argument("--dry-run", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
@@ -55,6 +61,9 @@ def main():
                "--shape", "train_4k"] + (["--multi-pod"] if args.multi_pod else [])
         raise SystemExit(subprocess.call(cmd, env=dict(os.environ)))
 
+    from repro.launch import compile_cache
+    cache_dir = compile_cache.enable()
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -65,8 +74,13 @@ def main():
     from repro.models.config import FederatedConfig, LoRAConfig
     from repro.models.layers import init_params
 
-    cfg = get_config(args.arch, smoke=True)
-    print(f"[train] {args.arch} (reduced: {cfg.num_layers}L d{cfg.d_model}) "
+    cfg = get_config(args.arch, smoke=not args.full)
+    dev = jax.devices()[0]
+    print(f"[train] device {dev.platform} ({dev.device_kind}) "
+          f"x{len(jax.devices())}, compile cache {cache_dir}")
+    print(f"[train] {args.arch} "
+          f"({'full' if args.full else 'reduced'}: "
+          f"{cfg.num_layers}L d{cfg.d_model}) "
           f"strategy={args.strategy} d={args.density} r={args.rank} "
           f"engine={args.engine}")
     params = init_params(mdl.model_spec(cfg), jax.random.key(args.seed))

@@ -85,15 +85,18 @@ class ServingEngine:
             lambda s: jnp.zeros(s.shape, s.dtype), shapes)
         # jit retraces _prefill once per prompt-length bucket; the trace
         # generator draws lengths from a small bucket set to bound that.
+        # The backbone is an argument, not a closure capture: captured, it
+        # would be baked into every executable as a constant (a second
+        # copy on the device, and slower compiles).
         self._prefill = jax.jit(self._prefill_impl)
         self._decode = jax.jit(self._decode_impl)
         self._write_lane = jax.jit(self._write_lane_impl)
 
     # --- device closures ----------------------------------------------------
-    def _prefill_impl(self, pool, page, tokens):
+    def _prefill_impl(self, params, pool, page, tokens):
         lora = page_lora(pool, page)
         logits, row_cache = mdl.prefill(
-            self.params, self.cfg, {"tokens": tokens}, lora=lora,
+            params, self.cfg, {"tokens": tokens}, lora=lora,
             lora_scale=self.lora_scale, window=self.window,
             max_len=self.max_len)
         return jnp.argmax(logits[0, -1]).astype(jnp.int32), row_cache
@@ -103,10 +106,10 @@ class ServingEngine:
         return jax.tree.map(lambda bc, rc: bc.at[:, lane].set(rc[:, 0]),
                             batch_cache, row_cache)
 
-    def _decode_impl(self, pool, batch_cache, tokens, pos, gidx):
+    def _decode_impl(self, params, pool, batch_cache, tokens, pos, gidx):
         lora = paged_lora(pool, gidx)
         logits, new_cache = mdl.decode_step(
-            self.params, self.cfg, tokens, pos, batch_cache, lora=lora,
+            params, self.cfg, tokens, pos, batch_cache, lora=lora,
             lora_scale=self.lora_scale, window=self.window)
         return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), new_cache
 
@@ -137,7 +140,8 @@ class ServingEngine:
             for lane in sched.admit():
                 req = lane.request
                 tok, row_cache = self._prefill(
-                    self.cache.pool, jnp.asarray(lane.page, jnp.int32),
+                    self.params, self.cache.pool,
+                    jnp.asarray(lane.page, jnp.int32),
                     jnp.asarray(np.asarray(req.prompt, np.int32)[None]))
                 batch_cache = self._write_lane(
                     batch_cache, row_cache, jnp.asarray(lane.index, jnp.int32))
@@ -151,8 +155,8 @@ class ServingEngine:
             active = [l for l in sched.lanes if l.active]
             if active:
                 out, batch_cache = self._decode(
-                    self.cache.pool, batch_cache, jnp.asarray(tokens),
-                    jnp.asarray(pos), jnp.asarray(gidx))
+                    self.params, self.cache.pool, batch_cache,
+                    jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(gidx))
                 out_host = np.asarray(out)
                 steps += 1
                 occupancy += len(active)

@@ -28,9 +28,14 @@ import pytest
 
 MATRIX = ["flasc", "hetlora_weighted", "flocora", "fused_quant"]
 
+# Single-threaded Eigen contractions: XLA:CPU's multi-threaded Eigen splits
+# a dot's work by its operand shapes, so one client per device (sharded)
+# and four clients batched on one device (sim) would sum the same products
+# in different orders.  Pinning it keeps the comparison about the program.
 SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                           "--xla_cpu_multi_thread_eigen=false")
 import json
 import tempfile
 import jax
