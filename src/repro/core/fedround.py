@@ -118,7 +118,8 @@ def _client_update(flat0, cbatch, m_train, up_pipe: tp.Pipeline, *,
     mu0 = jnp.zeros_like(flat0) if mu0 is None else mu0
     (flatT, muT), losses = jax.lax.scan(grad_step, (flat0, mu0), cbatch)
     delta = flat0 - flatT                                     # pseudo-gradient sign
-    msg = up_pipe(delta, key=up_key)
+    with jax.named_scope("fedround.upload"):
+        msg = up_pipe(delta, key=up_key)
     return msg.values, msg.nnz, jnp.mean(losses), muT
 
 
@@ -279,88 +280,98 @@ def federated_round(flatP, server_state, sstate, client_batches, rng, *,
     round_idx = server_state["round"]
     n_clients = jax.tree.leaves(client_batches)[0].shape[0]
 
-    m_down_global = strat.download_mask(flatP, sstate, round_idx)
-    P_base = strat.download_base(flatP, sstate)
-    ctx = meta.plan_context(n_clients, round_idx=round_idx)
-    plans = [strat.client_plan(m_down_global, c, ctx) for c in range(n_clients)]
+    # the download (server mask, base, plans) and the vmapped client block
+    # share one named scope; the device-trace readers attribute time by it
+    with jax.named_scope("fedround.clients"):
+        m_down_global = strat.download_mask(flatP, sstate, round_idx)
+        P_base = strat.download_base(flatP, sstate)
+        ctx = meta.plan_context(n_clients, round_idx=round_idx)
+        plans = [strat.client_plan(m_down_global, c, ctx)
+                 for c in range(n_clients)]
 
-    # --- per-message quantization keys (stochastic rounding) --------------
-    use_keys = rng is not None and (s.quant_bits_up or s.quant_bits_down)
-    qkeys = jax.random.split(rng, n_clients + 1) if use_keys else None
-    kdown = qkeys[-1] if use_keys else None     # shared: one broadcast message
-    upkeys, ax_key = (qkeys[:-1], 0) if use_keys else (None, None)
+        # --- per-message quantization keys (stochastic rounding) ----------
+        use_keys = rng is not None and (s.quant_bits_up or s.quant_bits_down)
+        qkeys = jax.random.split(rng, n_clients + 1) if use_keys else None
+        kdown = qkeys[-1] if use_keys else None  # shared: one broadcast message
+        upkeys, ax_key = (qkeys[:-1], 0) if use_keys else (None, None)
 
-    out, (m_down_cs, ax_down) = _run_clients(
-        P_base, plans, client_batches, s, loss_of=loss_of, meta=meta, fed=fed,
-        kdown=kdown, upkeys=upkeys, ax_key=ax_key,
-        spmd_axis_name=spmd_axis_name, round_idx=round_idx,
-        client_mu=client_mu)
-    if client_mu is None:
-        (deltas, nnzs, losses, down_nnzs), mu_out = out, None
-    else:
-        deltas, nnzs, losses, down_nnzs, mu_out = out
-    if spmd_axis_name:
-        # all-gather the (n_clients, p_len) deltas before aggregation so
-        # the cross-client reduce runs replicated in program order — a
-        # partitioner-chosen all-reduce over the sharded client axis picks
-        # its own association, off the single-device result by an ulp
-        # (no-op without an activation_sharding context)
-        from repro.launch.shardings import gather_replicated
-        deltas = gather_replicated(deltas)
+        out, (m_down_cs, ax_down) = _run_clients(
+            P_base, plans, client_batches, s, loss_of=loss_of, meta=meta,
+            fed=fed, kdown=kdown, upkeys=upkeys, ax_key=ax_key,
+            spmd_axis_name=spmd_axis_name, round_idx=round_idx,
+            client_mu=client_mu)
+        if client_mu is None:
+            (deltas, nnzs, losses, down_nnzs), mu_out = out, None
+        else:
+            deltas, nnzs, losses, down_nnzs, mu_out = out
 
-    lr_down = tp.lowrank_stage(s, "down")
-    if lr_down is not None and lr_down.active(meta.p_len):
-        # low-rank download: every message is the factor matrices, not the
-        # masked support — bill what the transport actually transmitted
-        down_nnz = jnp.mean(down_nnzs)
-    elif ax_down is None:   # shared mask: bill the global mask support
-        down_nnz = jnp.sum(jnp.asarray(m_down_cs).astype(jnp.float32))
-    else:                   # per-client masks: average per-client size
-        down_nnz = jnp.mean(down_nnzs)
+        lr_down = tp.lowrank_stage(s, "down")
+        if lr_down is not None and lr_down.active(meta.p_len):
+            # low-rank download: every message is the factor matrices, not
+            # the masked support — bill what the transport transmitted
+            down_nnz = jnp.mean(down_nnzs)
+        elif ax_down is None:   # shared mask: bill the global mask support
+            down_nnz = jnp.sum(jnp.asarray(m_down_cs).astype(jnp.float32))
+        else:                   # per-client masks: average per-client size
+            down_nnz = jnp.mean(down_nnzs)
 
     # --- aggregate + server update ----------------------------------------
-    if fed.dp_clip > 0.0:
-        # DP noise calibration assumes uniform averaging; refuse to silently
-        # drop a strategy's weighted aggregation rather than mis-account it
-        if not strat.uniform_aggregation:
-            raise NotImplementedError(
-                f"{strat.kind}: non-uniform Strategy.aggregate is "
-                "unsupported with DP clipping (dp_clip > 0)")
-        # the fallback key must still rotate with the round: a bare
-        # key(0) replays the identical noise draw every round, which is
-        # not DP — it is a fixed bias the server optimizer learns around
-        key = (rng if rng is not None
-               else jax.random.fold_in(jax.random.key(0), round_idx))
-        pseudo_grad, _ = dp_mod.dp_aggregate(deltas, fed.dp_clip, fed.dp_noise, key)
-    else:
-        pseudo_grad = _aggregate_uploads(strat, deltas, ctx)
+    with jax.named_scope("fedround.aggregate"):
+        if spmd_axis_name:
+            # all-gather the (n_clients, p_len) deltas before aggregation so
+            # the cross-client reduce runs replicated in program order — a
+            # partitioner-chosen all-reduce over the sharded client axis
+            # picks its own association, off the single-device result by
+            # an ulp (no-op without an activation_sharding context)
+            from repro.launch.shardings import gather_replicated
+            deltas = gather_replicated(deltas)
+        if fed.dp_clip > 0.0:
+            # DP noise calibration assumes uniform averaging; refuse to
+            # silently drop a strategy's weighted aggregation rather than
+            # mis-account it
+            if not strat.uniform_aggregation:
+                raise NotImplementedError(
+                    f"{strat.kind}: non-uniform Strategy.aggregate is "
+                    "unsupported with DP clipping (dp_clip > 0)")
+            # the fallback key must still rotate with the round: a bare
+            # key(0) replays the identical noise draw every round, which is
+            # not DP — it is a fixed bias the server optimizer learns around
+            key = (rng if rng is not None
+                   else jax.random.fold_in(jax.random.key(0), round_idx))
+            pseudo_grad, _ = dp_mod.dp_aggregate(deltas, fed.dp_clip,
+                                                 fed.dp_noise, key)
+        else:
+            pseudo_grad = _aggregate_uploads(strat, deltas, ctx)
 
-    if fed.server_opt == "adam":
-        flatP, opt = adam_update(flatP, pseudo_grad, server_state["opt"],
-                                 fed.server_lr, fed.adam_b1, fed.adam_b2,
-                                 fed.adam_eps)
-    else:   # FedAvg/FedSGD rule (paper Appendix A): W <- W - lr * mean(delta)
-        flatP = flatP - fed.server_lr * pseudo_grad
-        opt = server_state["opt"]
+    with jax.named_scope("fedround.server_update"):
+        if fed.server_opt == "adam":
+            flatP, opt = adam_update(flatP, pseudo_grad, server_state["opt"],
+                                     fed.server_lr, fed.adam_b1, fed.adam_b2,
+                                     fed.adam_eps)
+        else:   # FedAvg/FedSGD rule (paper Appendix A): W <- W - lr * mean(delta)
+            flatP = flatP - fed.server_lr * pseudo_grad
+            opt = server_state["opt"]
 
-    sstate, flatP = st.call_post_round(strat, sstate, flatP, P_base=P_base,
-                                       m_down=m_down_global,
-                                       round_idx=round_idx, ctx=ctx)
-    server_state = {"opt": opt, "round": round_idx + 1}
+        sstate, flatP = st.call_post_round(strat, sstate, flatP, P_base=P_base,
+                                           m_down=m_down_global,
+                                           round_idx=round_idx, ctx=ctx)
+        server_state = {"opt": opt, "round": round_idx + 1}
 
-    metrics = {
-        "loss": jnp.mean(losses),
-        "down_nnz": down_nnz,
-        "up_nnz": jnp.sum(nnzs),
-        "grad_norm": jnp.linalg.norm(pseudo_grad),
-        # per-message sizes for the ledger's per-message index/bitmap coding
-        "down_nnz_clients": down_nnzs,
-        "up_nnz_clients": nnzs,
-        # per-client losses: engines derive the *recorded* loss from these
-        # on the host (fused device reductions are association-dependent
-        # per program, so their scalars differ across engine backends)
-        "loss_clients": losses,
-    }
+        metrics = {
+            "loss": jnp.mean(losses),
+            "down_nnz": down_nnz,
+            "up_nnz": jnp.sum(nnzs),
+            "grad_norm": jnp.linalg.norm(pseudo_grad),
+            # per-message sizes for the ledger's per-message index/bitmap
+            # coding
+            "down_nnz_clients": down_nnzs,
+            "up_nnz_clients": nnzs,
+            # per-client losses: engines derive the *recorded* loss from
+            # these on the host (fused device reductions are
+            # association-dependent per program, so their scalars differ
+            # across engine backends)
+            "loss_clients": losses,
+        }
     if mu_out is not None:
         metrics["client_mu"] = mu_out
     return flatP, server_state, sstate, metrics
@@ -535,27 +546,32 @@ def make_client_phase_fn(loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
     def phase(params, flatP, sstate, round_idx, client_batches, rng):
         loss = (loss_of if params is None
                 else functools.partial(loss_of, params))
-        m_down_global = strat.download_mask(flatP, sstate, round_idx)
-        P_base = strat.download_base(flatP, sstate)
-        ctx = meta.plan_context(fed.n_clients, round_idx=round_idx)
-        plans = [strat.client_plan(m_down_global, c, ctx) for c in slots]
+        with jax.named_scope("fedround.clients"):
+            m_down_global = strat.download_mask(flatP, sstate, round_idx)
+            P_base = strat.download_base(flatP, sstate)
+            ctx = meta.plan_context(fed.n_clients, round_idx=round_idx)
+            plans = [strat.client_plan(m_down_global, c, ctx) for c in slots]
 
-        use_keys = rng is not None and (s.quant_bits_up or s.quant_bits_down)
-        if use_keys:
-            qkeys = jax.random.split(rng, fed.n_clients + 1)
-            kdown = qkeys[-1]
-            ups = [qkeys[c] if rep == 0 else jax.random.fold_in(qkeys[c], rep)
-                   for c, rep in zip(slots, repeats)]
-            upkeys, ax_key = jnp.stack(ups), 0
-        else:
-            kdown, upkeys, ax_key = None, None, None
+            use_keys = rng is not None and (s.quant_bits_up
+                                            or s.quant_bits_down)
+            if use_keys:
+                qkeys = jax.random.split(rng, fed.n_clients + 1)
+                kdown = qkeys[-1]
+                ups = [qkeys[c] if rep == 0
+                       else jax.random.fold_in(qkeys[c], rep)
+                       for c, rep in zip(slots, repeats)]
+                upkeys, ax_key = jnp.stack(ups), 0
+            else:
+                kdown, upkeys, ax_key = None, None, None
 
-        (deltas, nnzs, losses, down_nnzs), _ = _run_clients(
-            P_base, plans, client_batches, s, loss_of=loss, meta=meta,
-            fed=fed, kdown=kdown, upkeys=upkeys, ax_key=ax_key,
-            round_idx=round_idx)
+            (deltas, nnzs, losses, down_nnzs), _ = _run_clients(
+                P_base, plans, client_batches, s, loss_of=loss, meta=meta,
+                fed=fed, kdown=kdown, upkeys=upkeys, ax_key=ax_key,
+                round_idx=round_idx)
         if pack_cap:
-            idx, val, pnnz = ft.pack_values_batch(deltas, pack_cap)
+            # the sparse aggregation's pack, as in `_aggregate_uploads`
+            with jax.named_scope("fedround.aggregate"):
+                idx, val, pnnz = ft.pack_values_batch(deltas, pack_cap)
             return deltas, nnzs, losses, down_nnzs, idx, val, pnnz
         return deltas, nnzs, losses, down_nnzs
 
@@ -613,28 +629,32 @@ def make_server_phase_fn(meta: FlatMeta, fed: FederatedConfig,
 
     def fn(flatP, server_state, sstate, *rest):
         round_idx = server_state["round"]
-        m_down = strat.download_mask(flatP, sstate, round_idx)
-        P_base = strat.download_base(flatP, sstate)
+        with jax.named_scope("fedround.server_update"):    # for post_round
+            m_down = strat.download_mask(flatP, sstate, round_idx)
+            P_base = strat.download_base(flatP, sstate)
         ctx = meta.plan_context(fed.n_clients, round_idx=round_idx,
                                 cohort_slots=cohort_slots)
-        if sparse:
-            idx, val, weights = rest
-            pseudo_grad = strat.aggregate_sparse(
-                idx, val * weights[:, None], ctx)
-        else:
-            deltas, weights = rest
-            pseudo_grad = strat.aggregate(deltas * weights[:, None], ctx)
+        with jax.named_scope("fedround.aggregate"):
+            if sparse:
+                idx, val, weights = rest
+                pseudo_grad = strat.aggregate_sparse(
+                    idx, val * weights[:, None], ctx)
+            else:
+                deltas, weights = rest
+                pseudo_grad = strat.aggregate(deltas * weights[:, None], ctx)
 
-        if fed.server_opt == "adam":
-            flatP2, opt = adam_update(flatP, pseudo_grad, server_state["opt"],
-                                      fed.server_lr, fed.adam_b1, fed.adam_b2,
-                                      fed.adam_eps)
-        else:   # FedAvg/FedSGD rule (paper Appendix A)
-            flatP2 = flatP - fed.server_lr * pseudo_grad
-            opt = server_state["opt"]
+        with jax.named_scope("fedround.server_update"):
+            if fed.server_opt == "adam":
+                flatP2, opt = adam_update(flatP, pseudo_grad,
+                                          server_state["opt"], fed.server_lr,
+                                          fed.adam_b1, fed.adam_b2,
+                                          fed.adam_eps)
+            else:   # FedAvg/FedSGD rule (paper Appendix A)
+                flatP2 = flatP - fed.server_lr * pseudo_grad
+                opt = server_state["opt"]
 
-        sstate2, flatP2 = st.call_post_round(strat, sstate, flatP2,
-                                             P_base=P_base, m_down=m_down,
-                                             round_idx=round_idx, ctx=ctx)
+            sstate2, flatP2 = st.call_post_round(strat, sstate, flatP2,
+                                                 P_base=P_base, m_down=m_down,
+                                                 round_idx=round_idx, ctx=ctx)
         return flatP2, {"opt": opt, "round": round_idx + 1}, sstate2
     return fn

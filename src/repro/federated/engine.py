@@ -48,11 +48,13 @@ from typing import Any, Callable, ClassVar, Dict, List, Optional, Sequence, Type
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import comm as comm_mod
 from repro.core import fedround
 from repro.core import strategies as st
 from repro.core import transport as tp
+from repro.core.spans import gc_spans
 from repro.federated import async_clock as ac
 from repro.federated import population as popn
 from repro.models.config import FederatedConfig
@@ -376,28 +378,39 @@ class Engine:
         step = self.compile(plan)
         chunk_step = None
         try:
-            r = state.round
-            while r < state.rounds:
-                n = self._chunk_len(r, state, callbacks)
-                if n == 1:
-                    key = jax.random.fold_in(base_key, r)
-                    state.flatP, state.server, state.sstate, metrics = step(
-                        *pargs, state.flatP, state.server, state.sstate,
-                        data(r), key)
-                    per_round = [metrics]
-                else:
-                    if chunk_step is None:
-                        chunk_step = self._compile_chunk(plan)
-                    batches = _tree_stack([data(i) for i in range(r, r + n)])
-                    rids = jnp.arange(r, r + n, dtype=jnp.int32)
-                    state.flatP, state.server, state.sstate, ms = chunk_step(
-                        *pargs, state.flatP, state.server, state.sstate,
-                        batches, rids, base_key)
-                    per_round = [jax.tree.map(lambda x, i=i: x[i], ms)
-                                 for i in range(n)]
-                for i, m in enumerate(per_round):
-                    self._finish_round(state, r + i, m, callbacks)
-                r += n
+            with gc_spans():
+                r = state.round
+                while r < state.rounds:
+                    n = self._chunk_len(r, state, callbacks)
+                    with TraceAnnotation("engine.round", round=r, rounds=n):
+                        if n == 1:
+                            with TraceAnnotation("engine.data"):
+                                batch = data(r)
+                            with TraceAnnotation("engine.dispatch"):
+                                key = jax.random.fold_in(base_key, r)
+                                (state.flatP, state.server, state.sstate,
+                                 metrics) = step(
+                                    *pargs, state.flatP, state.server,
+                                    state.sstate, batch, key)
+                            per_round = [metrics]
+                        else:
+                            if chunk_step is None:
+                                chunk_step = self._compile_chunk(plan)
+                            with TraceAnnotation("engine.data"):
+                                batches = _tree_stack(
+                                    [data(i) for i in range(r, r + n)])
+                            with TraceAnnotation("engine.dispatch"):
+                                rids = jnp.arange(r, r + n, dtype=jnp.int32)
+                                (state.flatP, state.server, state.sstate,
+                                 ms) = chunk_step(
+                                    *pargs, state.flatP, state.server,
+                                    state.sstate, batches, rids, base_key)
+                            per_round = [jax.tree.map(lambda x, i=i: x[i], ms)
+                                         for i in range(n)]
+                        for i, m in enumerate(per_round):
+                            with TraceAnnotation("engine.finish"):
+                                self._finish_round(state, r + i, m, callbacks)
+                    r += n
         except StopRun:
             pass
         return state
@@ -456,26 +469,39 @@ class Engine:
         pre = popn.CohortPrefetcher(pop.store, pop.sampler)
         pop.last_prefetcher = pre
         try:
-            r = state.round
-            while r < state.rounds:
-                ids, mu_dev = pre.take(r)
-                key = jax.random.fold_in(base_key, r)
-                state.flatP, state.server, state.sstate, metrics = step(
-                    *pargs, state.flatP, state.server, state.sstate, data(r),
-                    mu_dev, key)
-                if pop.prefetch and r + 1 < state.rounds:
-                    # the jitted step dispatched asynchronously: stage
-                    # round r+1 while round r computes; `exclude` defers
-                    # any gather the commit below would invalidate
-                    pre.prefetch(r + 1, exclude=ids)
-                # this pull blocks on round r's device work
-                mu_out = np.asarray(metrics.pop("client_mu"), np.float32)
-                pop.store.commit_cohort(ids, mu_out)
-                if any(cb.wants_state(r, state.rounds) for cb in callbacks):
-                    state.aux = {"population": pop.store.to_arrays()}
-                self._finish_round(state, r, metrics, callbacks,
-                                   extra={"cohort": ids.tolist()})
-                r += 1
+            with gc_spans():
+                r = state.round
+                while r < state.rounds:
+                    with TraceAnnotation("engine.round", round=r, rounds=1):
+                        with TraceAnnotation("engine.stage"):
+                            ids, mu_dev = pre.take(r)
+                        with TraceAnnotation("engine.data"):
+                            batch = data(r)
+                        with TraceAnnotation("engine.dispatch"):
+                            key = jax.random.fold_in(base_key, r)
+                            (state.flatP, state.server, state.sstate,
+                             metrics) = step(
+                                *pargs, state.flatP, state.server,
+                                state.sstate, batch, mu_dev, key)
+                        if pop.prefetch and r + 1 < state.rounds:
+                            # the jitted step dispatched asynchronously:
+                            # stage round r+1 while round r computes;
+                            # `exclude` defers any gather the commit below
+                            # would invalidate
+                            with TraceAnnotation("engine.stage"):
+                                pre.prefetch(r + 1, exclude=ids)
+                        # this pull blocks on round r's device work
+                        with TraceAnnotation("engine.sync"):
+                            mu_out = np.asarray(metrics.pop("client_mu"),
+                                                np.float32)
+                        pop.store.commit_cohort(ids, mu_out)
+                        if any(cb.wants_state(r, state.rounds)
+                               for cb in callbacks):
+                            state.aux = {"population": pop.store.to_arrays()}
+                        with TraceAnnotation("engine.finish"):
+                            self._finish_round(state, r, metrics, callbacks,
+                                               extra={"cohort": ids.tolist()})
+                    r += 1
         except StopRun:
             pass
         state.aux = {"population": pop.store.to_arrays()}
@@ -495,9 +521,11 @@ class Engine:
                       callbacks: Sequence[Callback],
                       extra: Optional[Dict[str, Any]] = None) -> None:
         # the recorded loss is the canonical host mean of the per-client
-        # losses, identical across engine backends (see `_mean_f32`)
-        loss = (_mean_f32(metrics["loss_clients"])
-                if "loss_clients" in metrics else float(metrics["loss"]))
+        # losses, identical across engine backends (see `_mean_f32`); its
+        # pull is the round's first wait on the device
+        with TraceAnnotation("engine.sync"):
+            loss = (_mean_f32(metrics["loss_clients"])
+                    if "loss_clients" in metrics else float(metrics["loss"]))
         record: Dict[str, Any] = {"round": round_idx, "loss": loss}
         if extra:
             record.update(extra)
@@ -509,19 +537,25 @@ class Engine:
         stop: Optional[StopRun] = None
         try:
             for cb in callbacks:
-                cb.on_round_end(ev)
+                _call_hook(cb, cb.on_round_end, ev)
             if ev.evaluated:
                 for cb in callbacks:
-                    cb.on_eval(ev)
+                    _call_hook(cb, cb.on_eval, ev)
         except StopRun as e:
             stop = e
         state.history.append(record)
         state.round = round_idx + 1
         if ev.checkpoint_due and stop is None:
             for cb in callbacks:
-                cb.on_checkpoint(ev)
+                _call_hook(cb, cb.on_checkpoint, ev)
         if stop is not None:
             raise stop
+
+
+def _call_hook(cb: Callback, hook, ev: RoundEvent) -> None:
+    """One callback hook call, under the span `engine.callback.<Class>`."""
+    with TraceAnnotation("engine.callback." + type(cb).__name__):
+        hook(ev)
 
 
 _ENGINES: Dict[str, Type[Engine]] = {}

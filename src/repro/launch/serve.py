@@ -8,7 +8,8 @@ cache report.
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b \
       --clients 8 --pages 4 --lanes 4 --requests 16
 
-`--full` serves the full-width config (e.g. `--arch gpt2-small --full`).
+`--full` serves the full-width config (e.g. `--arch gpt2-small --full`);
+`--profile DIR` writes a profile of the serving loop (docs/serving.md).
 
 The heavy lifting all lives in `repro.serving` (see docs/serving.md);
 this module only assembles the reduced architecture and the synthetic
@@ -17,6 +18,7 @@ tenant population.
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 
 def main():
@@ -37,6 +39,10 @@ def main():
     ap.add_argument("--full", action="store_true",
                     help="serve the full-width config instead of the "
                          "reduced smoke variant")
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="serve under jax.profiler.trace(DIR): the serving "
+                         "loop's and the cache's spans land in one trace "
+                         "with the device's ops (docs/serving.md)")
     args = ap.parse_args()
 
     from repro.launch import compile_cache
@@ -84,7 +90,9 @@ def main():
           f"{args.clients} tenants / {args.pages} pages / {args.lanes} lanes")
     eng = ServingEngine(params, cfg, cache, n_lanes=args.lanes,
                         lora_scale=lcfg.scale, max_len=args.max_len)
-    rep = eng.run(trace)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        rep = eng.run(trace)
     st = rep.cache
     print(f"[serve] {len(rep.completions)}/{rep.requests} requests served: "
           f"{rep.generated_tokens} tokens in {rep.wall_s:.2f}s "

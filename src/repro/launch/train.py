@@ -10,6 +10,9 @@ architecture — a thin CLI over `Experiment` + the engine registry.
   # the full-width config (GPT-2 Small fits one TPU v5e chip whole):
   PYTHONPATH=src python -m repro.launch.train --arch gpt2-small --full
 
+  # a profile of the rounds, host spans and device ops on one clock:
+  PYTHONPATH=src python -m repro.launch.train --arch yi-9b --profile /tmp/prof
+
   # production lowering of the FULL config against the pod mesh (no compute):
   PYTHONPATH=src python -m repro.launch.train --arch yi-9b --dry-run [--multi-pod]
 
@@ -22,6 +25,7 @@ practical coded-bytes wire totals included.
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 
 def main():
@@ -47,6 +51,10 @@ def main():
     ap.add_argument("--full", action="store_true",
                     help="train the full-width config instead of the "
                          "reduced smoke variant")
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="run the rounds under jax.profiler.trace(DIR): the "
+                         "round loop's spans and the round's named scopes "
+                         "land in one trace (docs/engines.md)")
     ap.add_argument("--dry-run", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
@@ -124,7 +132,9 @@ def main():
     else:
         assert not args.fsdp, "--fsdp needs --mesh"
         exp.with_engine(args.engine, **engine_kw)
-    res = exp.run()
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        res = exp.run()
 
     led = res.ledger
     n, r = fed.n_clients, max(led.rounds, 1)

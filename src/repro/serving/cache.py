@@ -23,6 +23,7 @@ from typing import Any, Dict, Iterable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import io as ckpt_io
 
@@ -166,13 +167,13 @@ class PagedAdapterCache:
 
     # --- internals ----------------------------------------------------------
     def _write_page(self, lora, page: int) -> None:
-        padded = _map_pairs(lora, lambda v: _pad_rank(v, self.rank))
-
         def write(pool_leaf, new_leaf):
             return pool_leaf.at[..., page, :, :].set(
                 jnp.asarray(new_leaf, pool_leaf.dtype))
 
-        self.pool = jax.tree.map(write, self.pool, padded)
+        with TraceAnnotation("cache.write_page", page=page):
+            padded = _map_pairs(lora, lambda v: _pad_rank(v, self.rank))
+            self.pool = jax.tree.map(write, self.pool, padded)
 
     def _victim(self) -> Optional[int]:
         for cid in self._lru:                       # LRU order: oldest first

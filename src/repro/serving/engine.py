@@ -29,7 +29,9 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.core.spans import gc_spans
 from repro.models import model as mdl
 from repro.models.layers import spec_to_shape_dtype
 from repro.serving.cache import PagedAdapterCache, page_lora, paged_lora
@@ -130,43 +132,58 @@ class ServingEngine:
         steps = prefills = decode_tokens = 0
         occupancy = 0
         t0 = time.perf_counter()
-        while not sched.done():
-            if max_steps is not None and steps >= max_steps:
-                break
-            jump = sched.idle_jump()
-            if jump is not None:
-                now = max(now, jump)
-            sched.tick(now)
-            for lane in sched.admit():
-                req = lane.request
-                tok, row_cache = self._prefill(
-                    self.params, self.cache.pool,
-                    jnp.asarray(lane.page, jnp.int32),
-                    jnp.asarray(np.asarray(req.prompt, np.int32)[None]))
-                batch_cache = self._write_lane(
-                    batch_cache, row_cache, jnp.asarray(lane.index, jnp.int32))
-                li = lane.index
-                tokens[li] = int(tok)
-                pos[li] = req.prompt_len
-                gidx[li] = lane.page
-                prefills += 1
-                # the prompt's last logits already yielded token #1.
-                sched.push_token(lane, int(tok))
-            active = [l for l in sched.lanes if l.active]
-            if active:
-                out, batch_cache = self._decode(
-                    self.params, self.cache.pool, batch_cache,
-                    jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(gidx))
-                out_host = np.asarray(out)
-                steps += 1
-                occupancy += len(active)
-                for lane in active:
-                    li = lane.index
-                    tokens[li] = out_host[li]
-                    pos[li] += 1
-                    decode_tokens += 1
-                    sched.push_token(lane, int(out_host[li]))
-            now += self.step_dt
+        with gc_spans():
+            while not sched.done():
+                if max_steps is not None and steps >= max_steps:
+                    break
+                with TraceAnnotation("serve.step") as step_span:
+                    with TraceAnnotation("serve.admit"):
+                        jump = sched.idle_jump()
+                        if jump is not None:
+                            now = max(now, jump)
+                        sched.tick(now)
+                        admitted = sched.admit()
+                    for lane in admitted:
+                        req = lane.request
+                        with TraceAnnotation("serve.prefill"):
+                            tok, row_cache = self._prefill(
+                                self.params, self.cache.pool,
+                                jnp.asarray(lane.page, jnp.int32),
+                                jnp.asarray(
+                                    np.asarray(req.prompt, np.int32)[None]))
+                            batch_cache = self._write_lane(
+                                batch_cache, row_cache,
+                                jnp.asarray(lane.index, jnp.int32))
+                        with TraceAnnotation("serve.sync"):
+                            tok = int(tok)
+                        li = lane.index
+                        tokens[li] = tok
+                        pos[li] = req.prompt_len
+                        gidx[li] = lane.page
+                        prefills += 1
+                        # the prompt's last logits already yielded token #1.
+                        sched.push_token(lane, tok)
+                    active = [l for l in sched.lanes if l.active]
+                    step_span.set_metadata(active=len(active),
+                                           admitted=len(admitted))
+                    if active:
+                        with TraceAnnotation("serve.decode"):
+                            out, batch_cache = self._decode(
+                                self.params, self.cache.pool, batch_cache,
+                                jnp.asarray(tokens), jnp.asarray(pos),
+                                jnp.asarray(gidx))
+                        with TraceAnnotation("serve.sync"):
+                            out_host = np.asarray(out)
+                        steps += 1
+                        occupancy += len(active)
+                        with TraceAnnotation("serve.push"):
+                            for lane in active:
+                                li = lane.index
+                                tokens[li] = out_host[li]
+                                pos[li] += 1
+                                decode_tokens += 1
+                                sched.push_token(lane, int(out_host[li]))
+                    now += self.step_dt
         wall = time.perf_counter() - t0
         generated = decode_tokens + prefills
         return ServingReport(
