@@ -216,28 +216,32 @@ def _run_clients(P_base, plans, client_batches, s: st.StrategySpec, *,
 
 
 def _aggregate_uploads(strat: st.Strategy, deltas, ctx):
-    """`Strategy.aggregate`, routed through the sparse aggregation kernel
-    when the strategy opts in (`StrategySpec.sparse_aggregate`).
+    """`Strategy.aggregate`, or the sparse aggregation's sum when the
+    strategy opts in (`StrategySpec.sparse_aggregate`).
 
-    The sparse path packs each (p_len,) upload row into a static-capacity
-    (index, value) pair — in-kernel via the batched pack accumulator
-    (`fused_transport.pack_values_batch`, bit-identical to the
-    `pack_values` reference codec) — and scatter-adds the packed values
-    directly (`Strategy.aggregate_sparse`) — O(C * cap) instead of
-    O(C * p_len) aggregation reads.  A message whose nonzero support
-    exceeds the capacity (pathological threshold ties) flips the whole
-    round to the dense rule via `jnp.where`, so results are never
-    silently truncated.  Capacity gating is static
+    The uploads arrive here as one dense-embedded (C, p_len) stack that
+    is already on the device, so the sparse path does not pack them: it
+    folds the rows left to right from +0.0 (one `fori_loop`, so the
+    program's size does not grow with the cohort) and scales by 1/C.
+    Per coordinate that adds the nonzero values in row order, and adding
+    an exact zero changes no f32 sum, so the result is bit-equal to
+    `Strategy.aggregate_sparse` over the packed rows — the flat
+    scatter-add and the `edge_shards` tree alike — which the AsyncEngine
+    runs on jobs it carries in packed form.  A row whose nonzero support
+    exceeds the pack capacity (pathological threshold ties) flips the
+    whole round to the dense rule via `jnp.where`, exactly as a packed
+    row that overflows does.  Capacity gating is static
     (`strategies.sparse_aggregate_capacity`): unsupported specs compile
     the unmodified dense aggregation, byte for byte.
     """
     cap = st.sparse_aggregate_capacity(strat, ctx.p_len)
     if cap == 0:
         return strat.aggregate(deltas, ctx)
-    idx, val, pnnz = ft.pack_values_batch(deltas, cap)
-    overflow = jnp.any(pnnz > cap)
-    return jnp.where(overflow, strat.aggregate(deltas, ctx),
-                     strat.aggregate_sparse(idx, val, ctx))
+    n = deltas.shape[0]
+    acc = jax.lax.fori_loop(0, n, lambda c, acc: acc + deltas[c],
+                            jnp.zeros((ctx.p_len,), jnp.float32))
+    overflow = jnp.any(jnp.sum(deltas != 0, axis=1) > cap)
+    return jnp.where(overflow, strat.aggregate(deltas, ctx), acc / n)
 
 
 def federated_round(flatP, server_state, sstate, client_batches, rng, *,
@@ -569,7 +573,8 @@ def make_client_phase_fn(loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
                 fed=fed, kdown=kdown, upkeys=upkeys, ax_key=ax_key,
                 round_idx=round_idx)
         if pack_cap:
-            # the sparse aggregation's pack, as in `_aggregate_uploads`
+            # the packed jobs' pack; the synchronous round folds its
+            # dense rows instead (`_aggregate_uploads`), to the same bits
             with jax.named_scope("fedround.aggregate"):
                 idx, val, pnnz = ft.pack_values_batch(deltas, pack_cap)
             return deltas, nnzs, losses, down_nnzs, idx, val, pnnz

@@ -75,14 +75,18 @@ class StrategySpec:
     lowrank_up: int = 0
     lowrank_mode: str = "random"
     lowrank_seed: int = 0
-    # server-side sparse aggregation (docs/kernels.md): upload messages
-    # travel and aggregate in packed coded form (indices + values,
-    # `kernels.fused_transport.sparse_accumulate`) instead of dense
-    # (n_clients, p_len) stacks — O(total nnz) instead of O(C * p_len).
-    # Opt-in; only sound for uniform-averaging strategies with topk
-    # uploads (see `supports_sparse_aggregate`), and the engines fall
-    # back to the dense path whenever a message overflows its static
-    # pack capacity, so results are never silently truncated.
+    # server-side sparse aggregation (docs/kernels.md): the uploads'
+    # nonzeros are summed per coordinate in client order.  The
+    # AsyncEngine carries its jobs in packed coded form (indices +
+    # values) and scatter-adds them
+    # (`kernels.fused_transport.sparse_accumulate`); the synchronous
+    # round already holds the dense (n_clients, p_len) stack on the
+    # device and folds its rows, which gives the same bits, so its cost
+    # is O(C * p_len) reads there.  Opt-in; only sound for uniform-
+    # averaging strategies with topk uploads (see
+    # `supports_sparse_aggregate`), and the engines fall back to the
+    # dense mean whenever a message overflows its static pack capacity,
+    # so results are never silently truncated.
     sparse_aggregate: bool = False
     # hierarchical two-level aggregation (docs/scale.md): > 0 splits the
     # flat vector into that many contiguous index ranges, each pre-reduced
@@ -91,7 +95,9 @@ class StrategySpec:
     # partials.  Parameter-sharded (reduce-scatter style), so the per-
     # coordinate addition order matches the flat reduction exactly and the
     # result is bit-equal; 0 = flat single-level reduction.  Only takes
-    # effect on the sparse-aggregation path (`sparse_aggregate=True`).
+    # effect where uploads are aggregated packed, the AsyncEngine's
+    # sparse-aggregation path (`sparse_aggregate=True`); the synchronous
+    # round's row fold gives the same bits either way.
     edge_shards: int = 0
     # two_stage_ortho phase length: each A/B communication phase spans
     # this many consecutive rounds (1 = the paper's strict alternation).
@@ -257,9 +263,11 @@ class Strategy:
         sparse_accumulate`) then the uniform 1/C scaling.  Only called
         when `supports_sparse_aggregate` holds, i.e. for strategies whose
         `aggregate` is the base-class uniform mean, so the two paths
-        compute the same sum up to float summation order (bit-equality is
-        pinned *within* the sparse path: sim and async run this exact op
-        on identical packed inputs).  With `spec.edge_shards > 0` the
+        compute the same sum up to float summation order.  The
+        AsyncEngine's server phase runs this on its packed jobs; the
+        synchronous round folds its dense upload rows in client order
+        instead (`fedround._aggregate_uploads`), which gives these bits
+        exactly.  With `spec.edge_shards > 0` the
         scatter-add runs as the hierarchical edge tree
         (`fused_transport.hierarchical_accumulate`), which is bit-equal
         to the flat reduction by construction (docs/scale.md)."""

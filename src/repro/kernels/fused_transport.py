@@ -42,9 +42,11 @@ Padding zeros land in bin 0 (never counted by any probe — every probe
 index is >= 1) except in the all-zero-vector case, where every probe's
 `mid == 0` and the threshold is 0 on every path anyway.
 
-The server side closes the loop without densifying: `sparse_accumulate`
-gather-accumulates packed (indices, values) client rows straight into the
-(p_len,) pseudo-gradient sum — the CSR-style scatter-add shape — and
+The AsyncEngine's server phase closes the loop without densifying:
+`sparse_accumulate` gather-accumulates packed (indices, values) client
+rows straight into the (p_len,) pseudo-gradient sum — the CSR-style
+scatter-add shape (the synchronous round, whose uploads are already a
+dense stack on the device, folds the rows to the same bits) — and
 `pack_values` / `unpack_values` are the jnp reference codec the
 differential tests pin the kernels against (and the engines' bulk
 host-transfer coding).
@@ -525,8 +527,10 @@ def sparse_accumulate(idx: jax.Array, val: jax.Array, n: int) -> jax.Array:
     """Sum packed client messages into a dense (n,) vector without ever
     densifying the messages: one scatter-add over all (cap,) rows.  `idx`
     / `val` are (..., cap); sentinel slots (index >= n) drop.  This is the
-    server-side aggregation kernel — O(total nnz) gather-accumulate,
-    vs O(clients * p_len) for the dense mean."""
+    AsyncEngine's server-side aggregation of packed jobs — O(total nnz)
+    gather-accumulate, vs O(clients * p_len) for the dense mean — and is
+    bit-equal to the row-ordered sum of the dense rows, which is what
+    the synchronous round computes."""
     return jnp.zeros((n,), val.dtype).at[idx.reshape(-1)].add(
         val.reshape(-1), mode="drop")
 

@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 from repro.core import comm
+from repro.core import fedround
 from repro.core import quantization as qz
 from repro.core import selectors as sel
 from repro.core import sparsity as sp
@@ -425,6 +426,48 @@ def test_aggregate_sparse_matches_dense_mean():
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(strat.aggregate(X, ctx)),
                                rtol=1e-6, atol=1e-7)
+
+
+def _packed_aggregate(strat, deltas, ctx):
+    """The packed form of the sparse aggregation, which the AsyncEngine
+    runs on its jobs: the batched pack, the (flat or edge-tree)
+    scatter-add, and the dense mean when a row overflows the capacity."""
+    cap = st.sparse_aggregate_capacity(strat, ctx.p_len)
+    idx, val, pnnz = ft.pack_values_batch(deltas, cap)
+    return jnp.where(jnp.any(pnnz > cap), strat.aggregate(deltas, ctx),
+                     strat.aggregate_sparse(idx, val, ctx))
+
+
+@pytest.mark.parametrize("clients,edges,overflow", [
+    (1, 0, False), (1, 2, False), (3, 0, False), (3, 2, False),
+    (32, 0, False), (32, 2, False), (3, 0, True), (32, 2, True)])
+def test_round_fold_matches_packed_aggregation_bitwise(clients, edges,
+                                                       overflow):
+    """The synchronous round's row fold over the dense upload stack gives
+    the packed scatter-add's bits on fused 4-bit Top-K uploads, and the
+    dense mean's when a row holds more nonzeros than the capacity."""
+    p_len = 1000                        # not a lane multiple: the pack pads
+    strat = st.resolve(st.StrategySpec(
+        kind="flasc", density_up=0.25, selector="fused", quant_bits_up=4,
+        sparse_aggregate=True, edge_shards=edges))
+    pipe = tp.upload_pipeline(st.UploadRule.topk(0.25), quant_bits=4,
+                              selector="fused")
+    X = jax.random.normal(jax.random.key(clients), (clients, p_len))
+    keys = jax.random.split(jax.random.key(100 + clients), clients)
+    deltas = jax.vmap(lambda x, k: pipe(x, key=k).values)(X, keys)
+    if overflow:                        # one dense row: p_len > cap
+        deltas = deltas.at[clients // 2].set(X[clients // 2])
+    cap = st.sparse_aggregate_capacity(strat, p_len)
+    assert bool(jnp.any(jnp.sum(deltas != 0, axis=1) > cap)) == overflow
+    ctx = st.PlanContext(p_len=p_len, n_clients=clients, round_idx=0,
+                         rank_idx=None, is_b=None)
+    got = jax.jit(lambda d: fedround._aggregate_uploads(strat, d, ctx))(deltas)
+    want = jax.jit(lambda d: _packed_aggregate(strat, d, ctx))(deltas)
+    bits = lambda a: np.asarray(a).view(np.uint32)      # noqa: E731
+    np.testing.assert_array_equal(bits(got), bits(want))
+    if overflow:
+        np.testing.assert_array_equal(bits(got),
+                                      bits(strat.aggregate(deltas, ctx)))
 
 
 # ---------------------------------------------------------------------------

@@ -172,7 +172,9 @@ def round_hlo():
 
 
 def test_round_ops_carry_fedround_scopes(round_hlo):
-    ops = re.compile(r" = .*?\b(dot|scatter|custom-call)\(")
+    # `while` too: the synchronous aggregation is a row fold, a loop with
+    # no matmul, scatter or kernel in it
+    ops = re.compile(r" = .*?\b(dot|scatter|custom-call|while)\(")
     seen = set()
     for line in round_hlo.splitlines():
         if not ops.search(line):
@@ -183,3 +185,15 @@ def test_round_ops_carry_fedround_scopes(round_hlo):
         assert scope in SCOPES, line
         seen.add(scope)
     assert {"fedround.clients", "fedround.aggregate"} <= seen
+
+
+def test_round_aggregates_without_the_pack_or_a_scatter(round_hlo):
+    """The synchronous round sums its dense upload stack in place: the
+    batched pack kernel (a `tpu_custom_call` on the chip, interpreted ops
+    that carry its name here) and the packed scatter-add belong to the
+    AsyncEngine's packed jobs alone.  The client phase may scatter."""
+    assert "_pack_batch_kernel" not in round_hlo
+    for line in round_hlo.splitlines():
+        if re.search(r" = .*?\bscatter\(", line):
+            m = re.search(r'op_name="([^"]*)"', line)
+            assert m and _scope_of(m.group(1)) != "fedround.aggregate", line
