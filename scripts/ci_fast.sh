@@ -14,14 +14,15 @@
 #      parity/contract tests from tests/test_selectors.py and the
 #      exact_topk deprecation check, communication ledger, engine
 #      registry/callback/chunking units from tests/test_engine.py and
-#      tests/test_async_engine.py (incl. the sparse-aggregation
-#      sim==async bit-equality anchor), the fused one-pass transport
-#      differential/property layer from tests/test_fused_transport.py,
-#      the sharded-params 2-D mesh differential subset from
-#      tests/test_sharded_multidevice.py (one strategy, forced
-#      8-device subprocess, bit-equality + sharding inspection),
-#      the reprolint rule fixtures) — everything tagged
-#      @pytest.mark.fast.
+#      tests/test_async_*.py (incl. the sparse-aggregation sim==async
+#      bit-equality anchor), the fused one-pass transport
+#      differential/property layer from tests/test_fused_transport.py
+#      and tests/test_sparse_aggregation.py, the sharded-params 2-D mesh
+#      differential subset from tests/test_sharded_multidevice.py
+#      (bit-equality + sharding inspection read from the file's one
+#      forced 8-device child, which runs the whole strategy matrix and
+#      the resume leg: about 90 s more than one strategy alone), the
+#      reprolint rule fixtures) — everything tagged @pytest.mark.fast.
 #   4. the docs gate (scripts/check_docs.py: README/docs code
 #      references and registry tables must resolve,
 #      examples/quickstart.py must run).

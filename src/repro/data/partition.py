@@ -11,12 +11,25 @@ from typing import List, Sequence
 import numpy as np
 
 
+# Redraws before topping up: by then alpha has grown 1.5**64-fold, the
+# proportions are uniform to float precision, and their floor cuts (which
+# can starve the same clients in every class) no longer change.
+_MAX_DRAWS = 64
+
+
 def dirichlet_partition(labels: np.ndarray, n_clients: int, alpha: float,
                         seed: int = 0, min_size: int = 2) -> List[np.ndarray]:
-    """Per-client index lists with Dirichlet(alpha) label mixtures."""
+    """Per-client index lists with Dirichlet(alpha) label mixtures.
+
+    A draw that leaves a client under `min_size` examples is redrawn with a
+    smoother mixture; after `_MAX_DRAWS` draws the last one is topped up,
+    each short client taking examples from the largest."""
+    if len(labels) < n_clients * min_size:
+        raise ValueError(f"{len(labels)} examples cannot give {n_clients} "
+                         f"clients {min_size} each")
     rng = np.random.default_rng(seed)
     n_classes = int(labels.max()) + 1
-    while True:
+    for _ in range(_MAX_DRAWS):
         idx_by_client = [[] for _ in range(n_clients)]
         for c in range(n_classes):
             idx_c = np.where(labels == c)[0]
@@ -29,6 +42,10 @@ def dirichlet_partition(labels: np.ndarray, n_clients: int, alpha: float,
         if min(sizes) >= min_size:
             break
         alpha *= 1.5  # retry with slightly smoother mixture to avoid empty clients
+    else:
+        for short in idx_by_client:
+            while len(short) < min_size:
+                short.append(max(idx_by_client, key=len).pop())
     return [np.asarray(sorted(x), np.int64) for x in idx_by_client]
 
 

@@ -272,10 +272,11 @@ _CALL_PARENS = re.compile(r"^\s*[\w\-]+\((.*?)\)")
 def cpu_upcast_bytes(hlo: str) -> int:
     """Bytes of materialized bf16->f32 upcast buffers.  The CPU host backend
     has no native bf16 matmul and materializes f32 copies of bf16 weights —
-    as `parallel_convert*` call wrappers or kLoop convert fusions in current
-    XLA (the old `wrapped_convert` fusion naming is gone).  TPU executes bf16
-    dots natively, so these buffers would not exist on the target; subtract
-    from peak memory for the TPU-projected figure.
+    as `parallel_convert*` call wrappers or kLoop `wrapped_convert` fusions.
+    Printers differ on whether a call's parentheses carry operand types, so
+    an untyped operand is typed by its defining op.  TPU executes bf16 dots
+    natively, so these buffers would not exist on the target; subtract from
+    peak memory for the TPU-projected figure.
 
     Detection: in every *sequential* computation (entry / while bodies —
     i.e. not itself the target of a `calls=`/`to_apply=` edge, whose ops are
@@ -321,8 +322,11 @@ def _upcast_bytes_from_comps(comps: Dict[str, Computation]) -> int:
             pm = _CALL_PARENS.match(op.line)
             if pm is None:
                 continue
-            for sm in _ONE_SHAPE.finditer(pm.group(1)):
-                if sm.group(1) == "bf16" and _numel(sm) == out_numel:
+            for em in _OPERAND_ENTRY.finditer(pm.group(1)):
+                # inline operand type, else the operand's defining op
+                sm = _ONE_SHAPE.search(em.group(1) or
+                                       comp.shapes.get(em.group(2), ""))
+                if sm and sm.group(1) == "bf16" and _numel(sm) == out_numel:
                     total += out_numel * 4
                     break
     return total

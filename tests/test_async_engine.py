@@ -1,89 +1,20 @@
-"""AsyncEngine correctness anchors:
+"""AsyncEngine sync-equivalence anchors: with full concurrency, a full
+buffer, and a uniform `ClientSystemProfile` (the defaults), the async
+backend reproduces SimEngine bit for bit — history records, final weights,
+strategy state, eval accuracy, and ledger totals — for the 8 paper strategy
+kinds plus the `flocora` and `two_stage_ortho` baselines, for an odd
+cohort, for phased strategies, and under a full-participation sampler.
 
-  * sync-equivalence: with full concurrency, a full buffer, and a uniform
-    `ClientSystemProfile` (the defaults), the async backend reproduces
-    SimEngine bit for bit — history records, final weights, strategy
-    state, eval accuracy, and ledger totals — for the 8 paper strategy
-    kinds plus the `flocora` and `two_stage_ortho` baselines;
-  * staleness-weight and system-profile unit math;
-  * event-queue checkpoint/resume: a genuinely-async run (small buffer,
-    tiered speeds, jobs mid-flight at the snapshot) resumes bit-exactly;
-  * staleness drop policy terminates and bills dropped traffic;
-  * fig3 regression: under a 1/16 upload-bandwidth ratio, FLASC with
-    d_up=1/64 reaches the target accuracy in less simulated time than
-    dense LoRA, and the fig3 row helper emits the -1.0 sentinel instead
-    of a silent 1.0 when a baseline is missing.
+The sparse-aggregation and weighted paths are in test_async_sparse.py; the
+genuinely-async event queue (staleness, checkpoint/resume, partial
+participation, fig3) is in test_async_events.py.
 """
-import os
-
 import numpy as np
 import pytest
 
-from repro.core import strategies as st
-from repro.data import datasets as ds
-from repro.federated import async_clock as ac
-from repro.federated import engine as eng
-from repro.federated.api import Experiment
-
-N_CLIENTS = 4
-ROUNDS = 4
-EVAL_EVERY = 2
-
-# the last two entries enroll the PR 5 baselines (low-rank message
-# compression, the two-stage sparsified-orthogonal schedule) in the
-# identical sync-equivalence anchor as the 8 paper kinds
-KIND_KWARGS = {
-    "lora": {},
-    "flasc": {},
-    "flasc_ef": {},
-    "sparse_adapter": {},
-    "fedselect": {},
-    "adapter_lth": dict(lth_prune_every=2, lth_keep=0.9),
-    "ffa": {},
-    "hetlora": dict(hetlora_ranks=(1, 2, 3, 4)),
-    "flocora": dict(lowrank_down=4, lowrank_up=4),
-    "two_stage_ortho": {},
-}
-
-# keys only the async engine writes into history records
-ASYNC_KEYS = {"sim_time", "staleness", "applied", "dropped"}
-
-
-@pytest.fixture(scope="module")
-def task():
-    return ds.make_synth_image(n_examples=128, n_clients=8, n_patches=4,
-                               dim=16, seed=0, n_eval=128)
-
-
-def _experiment(task, kind="flasc", rounds=ROUNDS, **kw):
-    defaults = dict(density_down=0.5, density_up=0.5)
-    defaults.update(kw)
-    spec = st.StrategySpec(kind=kind, **defaults)
-    return (Experiment(task, strategy=spec)
-            .with_federation(n_clients=N_CLIENTS, local_batch=4)
-            .with_model(d_model=16, num_layers=1, num_heads=2, d_ff=32)
-            .with_lora(rank=4)
-            .with_training(rounds=rounds, eval_every=EVAL_EVERY,
-                           pretrain_steps=2))
-
-
-class _CaptureState(eng.Callback):
-    """Grabs the post-round state so tests can compare final weights."""
-
-    def on_round_end(self, ev):
-        import jax
-        self.flatP = np.asarray(ev.state.flatP)
-        self.sstate_leaves = [np.asarray(x)
-                              for x in jax.tree.leaves(ev.state.sstate)]
-
-
-LEDGER_ATTRS = ("down_values", "up_values", "down_bytes", "up_bytes",
-                "total_bytes", "down_coded_bytes", "up_coded_bytes",
-                "total_coded_bytes", "rounds")
-
-
-def _strip_async(record):
-    return {k: v for k, v in record.items() if k not in ASYNC_KEYS}
+from _fedexp import sim_run, task  # noqa: F401  (module fixtures)
+from _fedexp import (KIND_KWARGS, LEDGER_ATTRS, N_CLIENTS, CaptureState,
+                    experiment, strip_async)
 
 
 # ---------------------------------------------------------------------------
@@ -91,16 +22,15 @@ def _strip_async(record):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("kind", sorted(KIND_KWARGS))
-def test_async_defaults_reduce_to_sim_engine_bit_for_bit(task, kind):
-    cap_sim, cap_async = _CaptureState(), _CaptureState()
-    res_sim = (_experiment(task, kind, **KIND_KWARGS[kind])
-               .with_callbacks(cap_sim).run())
-    res_async = (_experiment(task, kind, **KIND_KWARGS[kind])
+def test_async_defaults_reduce_to_sim_engine_bit_for_bit(task, sim_run, kind):
+    res_sim, cap_sim = sim_run(kind)
+    cap_async = CaptureState()
+    res_async = (experiment(task, kind, **KIND_KWARGS[kind])
                  .with_engine("async").with_callbacks(cap_async).run())
 
     assert len(res_async.history) == len(res_sim.history)
     for rec_a, rec_s in zip(res_async.history, res_sim.history):
-        assert _strip_async(rec_a) == rec_s, rec_s["round"]
+        assert strip_async(rec_a) == rec_s, rec_s["round"]
         assert rec_a["staleness"] == 0.0    # full fresh cohorts only
         assert rec_a["applied"] == N_CLIENTS
     assert res_async.final_acc == res_sim.final_acc
@@ -116,381 +46,14 @@ def test_async_defaults_reduce_to_sim_engine_bit_for_bit(task, kind):
 def test_async_equivalence_holds_for_odd_cohort(task):
     """Non-power-of-two cohorts exercise the canonical host reductions
     (XLA's fused means are association-dependent there)."""
-    res_sim = _experiment(task, "hetlora",
-                          hetlora_ranks=(1, 2, 4)).with_federation(
+    res_sim = experiment(task, "hetlora",
+                         hetlora_ranks=(1, 2, 4)).with_federation(
                               n_clients=3, local_batch=4).run()
-    res_async = (_experiment(task, "hetlora", hetlora_ranks=(1, 2, 4))
+    res_async = (experiment(task, "hetlora", hetlora_ranks=(1, 2, 4))
                  .with_federation(n_clients=3, local_batch=4)
                  .with_engine("async").run())
     for rec_a, rec_s in zip(res_async.history, res_sim.history):
-        assert _strip_async(rec_a) == rec_s
-
-
-# ---------------------------------------------------------------------------
-# staleness / profile units
-# ---------------------------------------------------------------------------
-
-@pytest.mark.fast
-def test_staleness_weight_math():
-    for alpha in (0.0, 0.5, 1.0, 2.0):
-        assert ac.staleness_weight(0, alpha) == 1.0     # exactly
-    ws = [ac.staleness_weight(s, 0.5) for s in range(5)]
-    assert all(a > b for a, b in zip(ws, ws[1:]))       # monotone decay
-    assert ac.staleness_weight(3, 0.0) == 1.0           # alpha=0 disables
-    assert ac.staleness_weight(3, 1.0) == pytest.approx(0.25)
-    with pytest.raises(AssertionError):
-        ac.staleness_weight(-1, 0.5)
-
-
-@pytest.mark.fast
-def test_client_system_profile():
-    uniform = ac.ClientSystemProfile()
-    assert uniform.is_uniform
-    assert uniform.compute_time(3, 2) == 2.0
-    assert uniform.down_time(0, 2e6) == 2.0
-
-    tiered = ac.ClientSystemProfile.tiered(4, 4)
-    assert not tiered.is_uniform
-    assert tiered.speed_factors == (0.25, 0.5, 0.75, 1.0)
-    # slowest tier takes 4x the base step time; factors cycle past n
-    assert tiered.compute_time(0, 1) == 4.0
-    assert tiered.compute_time(4, 1) == 4.0
-    assert tiered.up_time(3, 1e6) == 1.0
-
-    logn = ac.ClientSystemProfile.lognormal(8, sigma=0.5, seed=1)
-    assert len(logn.speed_factors) == 8
-    assert all(f > 0 for f in logn.speed_factors)
-    # deterministic in the seed
-    assert logn == ac.ClientSystemProfile.lognormal(8, sigma=0.5, seed=1)
-
-    with pytest.raises(AssertionError):
-        ac.ClientSystemProfile(up_bw=0.0)
-
-
-@pytest.mark.fast
-def test_async_engine_registry_and_config_roundtrip():
-    assert "async" in eng.registered_engines()
-    e = eng.resolve_engine("async", buffer_size=2, staleness_alpha=1.0,
-                           max_staleness=3,
-                           profile=ac.ClientSystemProfile.tiered(4, 2))
-    assert isinstance(e, eng.AsyncEngine)
-    rebuilt = eng.resolve_engine("async", **e.config())
-    assert rebuilt.buffer_size == 2
-    assert rebuilt.max_staleness == 3
-    assert rebuilt.profile == e.profile     # dict round-trip -> tuples
-
-
-# ---------------------------------------------------------------------------
-# genuinely-async behavior
-# ---------------------------------------------------------------------------
-
-def _tiered_engine(**kw):
-    kw.setdefault("buffer_size", 2)
-    return eng.AsyncEngine(profile=ac.ClientSystemProfile.tiered(N_CLIENTS, 4),
-                           **kw)
-
-
-def test_async_staleness_and_virtual_time(task):
-    res = _experiment(task, rounds=8).with_engine(_tiered_engine()).run()
-    assert len(res.history) == 8
-    times = [h["sim_time"] for h in res.history]
-    assert all(a <= b for a, b in zip(times, times[1:]))
-    assert times[0] > 0.0
-    assert all(h["applied"] == 2 for h in res.history)
-    assert any(h["staleness"] > 0 for h in res.history)     # really async
-
-
-def test_async_max_staleness_drops_and_terminates(task):
-    res = (_experiment(task, rounds=5)
-           .with_engine(_tiered_engine(buffer_size=1, max_staleness=0))
-           .run())
-    assert len(res.history) == 5
-    assert sum(h["dropped"] for h in res.history) > 0
-    # dropped messages still billed: more upload messages than applied
-    applied = sum(h["applied"] for h in res.history)
-    assert res.ledger.up_values > 0
-    assert res.ledger.rounds == 5
-    assert applied == 5     # buffer of 1 applies one update per event
-
-
-class _StopAfterCheckpoint(eng.Callback):
-    """Simulates a crash right after a snapshot lands on disk."""
-
-    def on_checkpoint(self, ev):
-        raise eng.StopRun
-
-
-def test_async_checkpoint_resumes_event_queue_bit_exactly(task, tmp_path):
-    full = _experiment(task, rounds=8).with_engine(_tiered_engine()).run()
-
-    ckpt = str(tmp_path / "ckpt")
-    interrupted = (_experiment(task, rounds=8)
-                   .with_engine(_tiered_engine())
-                   .with_checkpoint(ckpt, every=3)
-                   .with_callbacks(_StopAfterCheckpoint())
-                   .run())
-    assert len(interrupted.history) == 3
-    assert os.path.exists(os.path.join(ckpt, "state-r3.npz"))
-
-    resumed_exp = Experiment.resume(ckpt)
-    assert isinstance(resumed_exp.engine, eng.AsyncEngine)
-    assert resumed_exp.engine.buffer_size == 2
-    assert resumed_exp.engine.profile == \
-        ac.ClientSystemProfile.tiered(N_CLIENTS, 4)
-    resumed = resumed_exp.run()
-    # bit-for-bit: floats, virtual timestamps, staleness — everything
-    assert resumed.history == full.history
-    assert resumed.final_acc == full.final_acc
-    for attr in LEDGER_ATTRS:
-        assert getattr(resumed.ledger, attr) == \
-            getattr(full.ledger, attr), attr
-
-
-@pytest.mark.fast
-def test_virtual_clock_array_roundtrip():
-    clock = ac.VirtualClock(n_clients=3, p_len=5)
-    clock.now, clock.seq = 2.5, 4
-    clock.job_counts[:] = (2, 1, 1)
-    clock.idle = [2]
-    job = ac.Job(slot=0, version=1, seq=3, t_start=2.0, t_finish=3.5,
-                 delta=np.arange(5, dtype=np.float32), loss=np.float32(0.25),
-                 down_nnz=5.0, up_nnz=2.0)
-    clock.submit(job)
-    clock.buffer.append(ac.Job(slot=1, version=0, seq=2, t_start=0.0,
-                               t_finish=2.5,
-                               delta=np.ones(5, np.float32),
-                               loss=np.float32(1.0), down_nnz=5.0,
-                               up_nnz=3.0))
-    clock.drop_down, clock.drop_up = [5.0], [1.0]
-
-    restored = ac.VirtualClock.from_arrays(clock.to_arrays(), 3, 5)
-    assert restored.now == 2.5 and restored.seq == 4
-    assert restored.idle == [2]
-    assert [e[2].seq for e in restored.inflight] == [3]
-    np.testing.assert_array_equal(restored.inflight[0][2].delta, job.delta)
-    assert restored.buffer[0].up_nnz == 3.0
-    assert restored.drop_down == [5.0] and restored.drop_up == [1.0]
-    np.testing.assert_array_equal(restored.job_counts, clock.job_counts)
-
-
-@pytest.mark.fast
-def test_async_weighted_aggregation_runs_with_partial_buffers(task):
-    """hetlora_weighted under partial buffers: the server phase is
-    specialized to each buffer's slot tuple (`cohort_slots`), so the
-    rank-coverage weighting counts exactly the rows present instead of
-    refusing (the PR 9 fix for the old full-fresh-cohort guard).  With a
-    uniform profile and full concurrency every aggregation event is a
-    deterministic half-cohort, so the run is reproducible and each entry
-    of the pseudo-gradient is scaled by the coverage of its own buffer —
-    which must differ from the unweighted trajectory."""
-    kw = dict(hetlora_ranks=(1, 2, 3, 4), hetlora_weighted=True)
-    res = (_experiment(task, "hetlora", **kw)
-           .with_engine("async", buffer_size=2).run())
-    assert all(rec["applied"] == 2 for rec in res.history)
-    assert all(np.isfinite(rec["loss"]) for rec in res.history)
-    again = (_experiment(task, "hetlora", **kw)
-             .with_engine("async", buffer_size=2).run())
-    assert [r["loss"] for r in res.history] == \
-        [r["loss"] for r in again.history]
-    unweighted = (_experiment(task, "hetlora",
-                              hetlora_ranks=(1, 2, 3, 4))
-                  .with_engine("async", buffer_size=2).run())
-    assert [r["loss"] for r in res.history] != \
-        [r["loss"] for r in unweighted.history]
-
-
-@pytest.mark.fast
-def test_hetlora_coverage_counts_buffer_slots():
-    """Unit-level pin of the slot-aware coverage: a partial buffer counts
-    only its own rank slices, and a repeated slot counts twice."""
-    spec = st.StrategySpec(kind="hetlora", hetlora_ranks=(1, 2, 3, 4),
-                           hetlora_weighted=True)
-    strat = st.resolve(spec)
-    rank_idx = np.asarray([0, 1, 2, 3])
-    full = st.PlanContext(n_clients=4, p_len=4, round_idx=0,
-                          rank_idx=rank_idx)
-    np.testing.assert_array_equal(strat.coverage(full), [4, 3, 2, 1])
-    part = st.PlanContext(n_clients=4, p_len=4, round_idx=0,
-                          rank_idx=rank_idx, cohort_slots=(1, 3))
-    # ranks present: 2 and 4 -> entry j covered by ranks > j
-    np.testing.assert_array_equal(strat.coverage(part), [2, 2, 1, 1])
-    rep = st.PlanContext(n_clients=4, p_len=4, round_idx=0,
-                         rank_idx=rank_idx, cohort_slots=(3, 3))
-    np.testing.assert_array_equal(strat.coverage(rep), [2, 2, 2, 2])
-
-
-@pytest.mark.fast
-def test_async_rejects_zero_buffer_and_concurrency(task):
-    """An explicit 0 is an error, not a silent fall-back to the
-    full-cohort default (None)."""
-    with pytest.raises(AssertionError):
-        _experiment(task).with_engine("async", buffer_size=0).run()
-    with pytest.raises(AssertionError):
-        _experiment(task).with_engine("async", concurrency=0).run()
-
-
-@pytest.mark.fast
-def test_async_refuses_dp(task):
-    """The refusal must *name the open ROADMAP item* ('DP noise
-    calibration under buffered/partial aggregation') and point at the
-    sync engines, whose per-round noise rotation is pinned by
-    tests/test_engine.py::test_dp_fallback_key_rotates_per_round — an
-    operator hitting this error should land on the actual state of DP
-    support, not a bare 'not implemented'."""
-    exp = (_experiment(task)
-           .with_federation(n_clients=N_CLIENTS, local_batch=4, dp_clip=1.0,
-                            dp_noise=0.1)
-           .with_engine("async"))
-    with pytest.raises(NotImplementedError, match="dp_clip") as ei:
-        exp.run()
-    msg = str(ei.value)
-    assert "DP noise calibration under buffered/partial aggregation" in msg
-    assert "ROADMAP" in msg
-    assert "fresh noise every round" in msg
-
-
-# ---------------------------------------------------------------------------
-# sparse aggregation (StrategySpec.sparse_aggregate): the packed
-# bulk-transfer path must preserve every anchor above
-# ---------------------------------------------------------------------------
-
-@pytest.mark.fast
-@pytest.mark.parametrize("kw", [
-    dict(kind="flasc"),                                     # packed path
-    dict(kind="flasc", selector="fused", quant_bits_up=4),  # + fused kernels
-    dict(kind="hetlora", hetlora_ranks=(1, 2, 3, 4),        # weighted
-         hetlora_weighted=True),                            # override: must
-], ids=["flasc", "flasc-fused-quant", "hetlora-weighted"])  # fall back dense
-def test_async_sparse_aggregation_reduces_to_sim_bit_for_bit(task, kw):
-    """sim == async bit-equality at sync defaults still holds with the
-    sparse aggregation kernel enabled — the flasc specs actually exercise
-    the packed scatter-add server phase, and hetlora_weighted (whose
-    `aggregate` override reads the dense stack) must be gated back onto
-    the dense path rather than mis-aggregated."""
-    kw = dict(kw, sparse_aggregate=True)
-    kind = kw.pop("kind")
-    if kind == "hetlora":
-        assert not st.supports_sparse_aggregate(
-            st.resolve(st.StrategySpec(kind=kind, **kw)))
-    cap_sim, cap_async = _CaptureState(), _CaptureState()
-    res_sim = _experiment(task, kind, **kw).with_callbacks(cap_sim).run()
-    res_async = (_experiment(task, kind, **kw)
-                 .with_engine("async").with_callbacks(cap_async).run())
-    for rec_a, rec_s in zip(res_async.history, res_sim.history):
-        assert _strip_async(rec_a) == rec_s, rec_s["round"]
-    assert res_async.final_acc == res_sim.final_acc
-    for attr in LEDGER_ATTRS:
-        assert getattr(res_async.ledger, attr) == \
-            getattr(res_sim.ledger, attr), attr
-    np.testing.assert_array_equal(cap_async.flatP, cap_sim.flatP)
-
-
-@pytest.mark.fast
-def test_async_weighted_aggregation_with_sparse_opt_in_partial_buffers(task):
-    """The sparse_aggregate opt-in never makes a weighted `aggregate`
-    override eligible for the packed path (it falls back dense), and the
-    slot-specialized dense phase runs partial buffers bit-identically to
-    the same spec without the opt-in."""
-    kw = dict(hetlora_ranks=(1, 2, 3, 4), hetlora_weighted=True)
-    sparse = (_experiment(task, "hetlora", sparse_aggregate=True, **kw)
-              .with_engine("async", buffer_size=2).run())
-    dense = (_experiment(task, "hetlora", **kw)
-             .with_engine("async", buffer_size=2).run())
-    assert [r["loss"] for r in sparse.history] == \
-        [r["loss"] for r in dense.history]
-
-
-def test_async_sparse_checkpoint_resumes_packed_queue_bit_exactly(
-        task, tmp_path):
-    """Event-queue checkpoint/resume with packed job deltas in flight:
-    the `delta_idx`/`delta_val` serialization must round-trip so a
-    resumed genuinely-async sparse run reproduces the uninterrupted one
-    bit for bit (and keeps aggregating through the sparse phase)."""
-    kw = dict(sparse_aggregate=True)
-    full = (_experiment(task, rounds=8, **kw)
-            .with_engine(_tiered_engine()).run())
-
-    ckpt = str(tmp_path / "ckpt")
-    interrupted = (_experiment(task, rounds=8, **kw)
-                   .with_engine(_tiered_engine())
-                   .with_checkpoint(ckpt, every=3)
-                   .with_callbacks(_StopAfterCheckpoint())
-                   .run())
-    assert len(interrupted.history) == 3
-    resumed = Experiment.resume(ckpt).run()
-    assert resumed.history == full.history
-    assert resumed.final_acc == full.final_acc
-    for attr in LEDGER_ATTRS:
-        assert getattr(resumed.ledger, attr) == \
-            getattr(full.ledger, attr), attr
-
-
-@pytest.mark.fast
-def test_virtual_clock_packed_delta_roundtrip():
-    """`_jobs_to_arrays` with mixed packed/dense jobs: the flag-walk
-    re-zips rows correctly and `dense_delta` recovers the dense form."""
-    clock = ac.VirtualClock(n_clients=2, p_len=6)
-    packed = (np.asarray([1, 4, 6, 6], np.int32),
-              np.asarray([2.0, -3.0, 0.0, 0.0], np.float32))
-    dense = np.asarray([0, 1, 0, 0, 5, 0], np.float32)
-    clock.buffer.append(ac.Job(slot=0, version=0, seq=0, t_start=0.0,
-                               t_finish=1.0, delta=packed,
-                               loss=np.float32(0.5), down_nnz=6.0,
-                               up_nnz=2.0))
-    clock.buffer.append(ac.Job(slot=1, version=0, seq=1, t_start=0.0,
-                               t_finish=1.5, delta=dense,
-                               loss=np.float32(0.25), down_nnz=6.0,
-                               up_nnz=2.0))
-    restored = ac.VirtualClock.from_arrays(clock.to_arrays(), 2, 6)
-    r0, r1 = restored.buffer
-    assert isinstance(r0.delta, tuple) and not isinstance(r1.delta, tuple)
-    np.testing.assert_array_equal(r0.delta[0], packed[0])
-    np.testing.assert_array_equal(r0.delta[1], packed[1])
-    np.testing.assert_array_equal(r1.delta, dense)
-    np.testing.assert_array_equal(
-        ac.dense_delta(r0.delta, 6),
-        np.asarray([0, 2, 0, 0, -3, 0], np.float32))
-    np.testing.assert_array_equal(ac.dense_delta(r1.delta, 6), dense)
-
-
-# ---------------------------------------------------------------------------
-# fig3 regression + row-helper sentinel
-# ---------------------------------------------------------------------------
-
-def test_fig3_flasc_sparse_upload_beats_dense_lora_sim_time(task):
-    """The paper's Fig. 3 claim on the virtual clock: under upload 16x
-    slower than download, FLASC d_up=1/64 reaches the target accuracy in
-    far less simulated time than dense LoRA."""
-    from benchmarks.fig3_async_bandwidth import sim_time_to_target
-    profile = ac.ClientSystemProfile(step_time=0.0, down_bw=1e6,
-                                     up_bw=1e6 / 16)
-    res_lora = (_experiment(task, "lora", rounds=6)
-                .with_engine(eng.AsyncEngine(profile=profile)).run())
-    res_flasc = (_experiment(task, "flasc", rounds=6, density_down=0.25,
-                             density_up=1 / 64)
-                 .with_engine(eng.AsyncEngine(profile=profile)).run())
-    target = 0.9 * min(res_lora.best_acc(), res_flasc.best_acc())
-    t_lora = sim_time_to_target(res_lora.history, target)
-    t_flasc = sim_time_to_target(res_flasc.history, target)
-    assert t_lora is not None and t_flasc is not None
-    assert t_flasc < t_lora
-
-
-@pytest.mark.fast
-def test_fig3_rel_row_sentinel():
-    """`base_t is None` (dense LoRA never reached target) must yield the
-    -1.0 sentinel, not a silent 1.0 — the bug the old inline code had."""
-    from benchmarks.fig3_async_bandwidth import rel_row, sim_time_to_target
-    assert rel_row("fig3", "s", "m", 5.0, None)["value"] == -1.0
-    assert rel_row("fig3", "s", "m", None, 3.0)["value"] == -1.0
-    assert rel_row("fig3", "s", "m", None, None)["value"] == -1.0
-    assert rel_row("fig3", "s", "m", 6.0, 3.0)["value"] == 2.0
-    assert rel_row("fig3", "s", "m", 3.0, 3.0)["value"] == 1.0
-    # the time readers skip non-eval records and unreached targets
-    hist = [{"round": 0, "loss": 1.0},
-            {"round": 1, "loss": 0.5, "acc": 0.4, "sim_time": 7.0}]
-    assert sim_time_to_target(hist, 0.3) == 7.0
-    assert sim_time_to_target(hist, 0.9) is None
+        assert strip_async(rec_a) == rec_s
 
 
 # ---------------------------------------------------------------------------
@@ -502,53 +65,34 @@ def test_two_stage_ortho_phases_match_sim_bit_for_bit(task, phase_len):
     """phase_len > 1 must produce the same phase schedule (and hence the
     same weights) on both engines; the phase index derives from the server
     round counter, which AsyncEngine advances once per aggregation."""
-    cap_sim, cap_async = _CaptureState(), _CaptureState()
-    res_sim = (_experiment(task, "two_stage_ortho", rounds=6,
-                           phase_len=phase_len)
+    cap_sim, cap_async = CaptureState(), CaptureState()
+    res_sim = (experiment(task, "two_stage_ortho", rounds=6,
+                          phase_len=phase_len)
                .with_callbacks(cap_sim).run())
-    res_async = (_experiment(task, "two_stage_ortho", rounds=6,
-                             phase_len=phase_len)
+    res_async = (experiment(task, "two_stage_ortho", rounds=6,
+                            phase_len=phase_len)
                  .with_engine("async").with_callbacks(cap_async).run())
     for rec_a, rec_s in zip(res_async.history, res_sim.history):
-        assert _strip_async(rec_a) == rec_s, rec_s["round"]
+        assert strip_async(rec_a) == rec_s, rec_s["round"]
     np.testing.assert_array_equal(cap_async.flatP, cap_sim.flatP)
 
 
 def test_two_stage_ortho_phase_len_changes_trajectory(task):
     """Sanity: the schedule knob is live (L=3 differs from L=1)."""
-    res_1 = _experiment(task, "two_stage_ortho", rounds=6, phase_len=1).run()
-    res_3 = _experiment(task, "two_stage_ortho", rounds=6, phase_len=3).run()
+    res_1 = experiment(task, "two_stage_ortho", rounds=6, phase_len=1).run()
+    res_3 = experiment(task, "two_stage_ortho", rounds=6, phase_len=3).run()
     assert [r["loss"] for r in res_1.history] != \
         [r["loss"] for r in res_3.history]
 
 
-def test_async_full_participation_sampler_reduces_to_sim(task):
+def test_async_full_participation_sampler_reduces_to_sim(task, sim_run):
     """A fraction sampler at participation=1.0 gates nothing, so the async
     run must stay bit-identical to the sim engine."""
-    res_sim = _experiment(task, "flasc").run()
-    res_async = (_experiment(task, "flasc")
+    res_sim, _ = sim_run("flasc")
+    res_async = (experiment(task, "flasc")
                  .with_engine("async",
                               sampler={"kind": "fraction",
                                        "participation": 1.0}).run())
     for rec_a, rec_s in zip(res_async.history, res_sim.history):
-        assert _strip_async(rec_a) == rec_s, rec_s["round"]
+        assert strip_async(rec_a) == rec_s, rec_s["round"]
     assert res_async.final_acc == res_sim.final_acc
-
-
-def test_async_partial_participation_runs_and_differs(task):
-    """participation < 1 throttles client starts: the run still completes
-    (FedBuff timeout flushes partial buffers), stays reproducible, and
-    diverges from the full-participation trajectory."""
-    def run():
-        return (_experiment(task, "flasc", rounds=6)
-                .with_engine("async",
-                             sampler={"kind": "fraction",
-                                      "participation": 0.5, "seed": 3})
-                .run())
-    res_a, res_b = run(), run()
-    assert [r["loss"] for r in res_a.history] == \
-        [r["loss"] for r in res_b.history]
-    assert all(np.isfinite(r["loss"]) for r in res_a.history)
-    res_full = _experiment(task, "flasc", rounds=6).with_engine("async").run()
-    assert [r["loss"] for r in res_a.history] != \
-        [r["loss"] for r in res_full.history]

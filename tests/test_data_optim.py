@@ -33,6 +33,19 @@ def test_dirichlet_partition_is_a_partition(n, clients, alpha, seed):
     assert all(len(p) >= 1 for p in parts)
 
 
+def test_dirichlet_partition_tops_up_when_redraws_cannot_help():
+    """54 examples over 16 clients: the floor cuts of near-uniform mixtures
+    leave the same clients short in every class however smooth alpha gets,
+    so redrawing alone never ends.  The last draw is topped up instead."""
+    labels = np.random.default_rng(139017).integers(0, 5, 54)
+    parts = dirichlet_partition(labels, 16, 100.0, seed=139017)
+    all_idx = np.concatenate(parts)
+    assert sorted(all_idx.tolist()) == list(range(54))
+    assert min(len(p) for p in parts) == 2
+    with pytest.raises(ValueError, match="cannot give"):
+        dirichlet_partition(labels, 28, 100.0)
+
+
 def test_dirichlet_alpha_controls_skew():
     labels = np.random.default_rng(0).integers(0, 10, 4000)
     skew_lo = label_heterogeneity(dirichlet_partition(labels, 32, 100.0, 1), labels)

@@ -17,43 +17,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _fedexp import sim_run, task  # noqa: F401  (module fixtures)
+from _fedexp import KIND_KWARGS, LEDGER_ATTRS, StopAfterCheckpoint, experiment
 from repro.core import fedround
 from repro.core import strategies as st
-from repro.data import datasets as ds
 from repro.data.pipeline import sample_round
 from repro.federated import engine as eng
 from repro.federated.api import Experiment
 
-N_CLIENTS = 4
-ROUNDS = 4
-EVAL_EVERY = 2
-
-KIND_KWARGS = {
-    "lora": {},
-    "flasc": {},
-    "flasc_ef": {},
-    "sparse_adapter": {},
-    "fedselect": {},
-    "adapter_lth": dict(lth_prune_every=2, lth_keep=0.9),
-    "ffa": {},
-    "hetlora": dict(hetlora_ranks=(1, 2, 3, 4)),
-}
+# the kinds that predate the engine API (the PR 5 baselines came later)
+LEGACY_KINDS = sorted(set(KIND_KWARGS) - {"flocora", "two_stage_ortho"})
 
 
 @pytest.fixture(scope="module")
-def task():
-    return ds.make_synth_image(n_examples=128, n_clients=8, n_patches=4,
-                               dim=16, seed=0, n_eval=128)
-
-
-def _experiment(task, kind="flasc", rounds=ROUNDS, **kw):
-    spec = st.StrategySpec(kind=kind, density_down=0.5, density_up=0.5, **kw)
-    return (Experiment(task, strategy=spec)
-            .with_federation(n_clients=N_CLIENTS, local_batch=4)
-            .with_model(d_model=16, num_layers=1, num_heads=2, d_ff=32)
-            .with_lora(rank=4)
-            .with_training(rounds=rounds, eval_every=EVAL_EVERY,
-                           pretrain_steps=2))
+def sim6(task):
+    """Six SimEngine rounds of FLASC."""
+    return experiment(task, rounds=6).run()
 
 
 def _legacy_run(exp):
@@ -108,26 +87,11 @@ def _legacy_run(exp):
     return history, ledger, acc, np.asarray(flatP), jax.tree.leaves(sstate)
 
 
-class _CaptureState(eng.Callback):
-    """Grabs the post-round state so tests can compare final weights."""
-
-    def on_round_end(self, ev):
-        self.flatP = np.asarray(ev.state.flatP)
-        self.sstate_leaves = [np.asarray(x)
-                              for x in jax.tree.leaves(ev.state.sstate)]
-
-
-LEDGER_ATTRS = ("down_values", "up_values", "down_bytes", "up_bytes",
-                "total_bytes", "down_coded_bytes", "up_coded_bytes",
-                "total_coded_bytes", "rounds")
-
-
-@pytest.mark.parametrize("kind", sorted(KIND_KWARGS))
-def test_sim_engine_bit_identical_to_prerefactor_loop(task, kind):
-    cap = _CaptureState()
-    res = _experiment(task, kind, **KIND_KWARGS[kind]).with_callbacks(cap).run()
+@pytest.mark.parametrize("kind", LEGACY_KINDS)
+def test_sim_engine_bit_identical_to_prerefactor_loop(task, sim_run, kind):
+    res, cap = sim_run(kind)
     hist_old, led_old, acc_old, P_old, ss_old = _legacy_run(
-        _experiment(task, kind, **KIND_KWARGS[kind]))
+        experiment(task, kind, **KIND_KWARGS[kind]))
 
     assert len(res.history) == len(hist_old)
     for rec_new, rec_old in zip(res.history, hist_old):
@@ -143,22 +107,22 @@ def test_sim_engine_bit_identical_to_prerefactor_loop(task, kind):
 
 
 @pytest.mark.parametrize("rounds_per_call", [1, 4])
-def test_sharded_engine_end_to_end_single_device(task, rounds_per_call):
+def test_sharded_engine_end_to_end_single_device(task, sim6, rounds_per_call):
     """The SPMD backend on a (1, 1) cpu mesh: same experiment, same ledger
     totals, matching losses, eval cadence preserved across scan chunks."""
-    sim = _experiment(task, rounds=6).run()
-    sh = (_experiment(task, rounds=6)
+    sh = (experiment(task, rounds=6)
           .with_engine("sharded", rounds_per_call=rounds_per_call)
           .run())
-    assert [h["round"] for h in sh.history] == [h["round"] for h in sim.history]
-    for a, b in zip(sh.history, sim.history):
+    assert [h["round"] for h in sh.history] == \
+        [h["round"] for h in sim6.history]
+    for a, b in zip(sh.history, sim6.history):
         assert a["loss"] == pytest.approx(b["loss"], rel=1e-5)
     for attr in LEDGER_ATTRS:
-        assert getattr(sh.ledger, attr) == getattr(sim.ledger, attr), attr
+        assert getattr(sh.ledger, attr) == getattr(sim6.ledger, attr), attr
     # eval rounds must land at the cadence even when chunked
     assert [h["round"] for h in sh.history if "acc" in h] == \
-        [h["round"] for h in sim.history if "acc" in h]
-    assert sh.final_acc == pytest.approx(sim.final_acc, abs=1e-6)
+        [h["round"] for h in sim6.history if "acc" in h]
+    assert sh.final_acc == pytest.approx(sim6.final_acc, abs=1e-6)
 
 
 @pytest.mark.parametrize("kind,kw", [
@@ -169,8 +133,8 @@ def test_baseline_kinds_run_under_sharded_engine(task, kind, kw):
     """The two named baselines (low-rank message compression / two-stage
     sparsified-orthogonal updates) run under the SPMD backend with zero
     engine edits: same ledger totals and history as SimEngine."""
-    sim = _experiment(task, kind, **kw).run()
-    sh = _experiment(task, kind, **kw).with_engine("sharded").run()
+    sim = experiment(task, kind, **kw).run()
+    sh = experiment(task, kind, **kw).with_engine("sharded").run()
     assert [h["round"] for h in sh.history] == \
         [h["round"] for h in sim.history]
     for a, b in zip(sh.history, sim.history):
@@ -180,20 +144,13 @@ def test_baseline_kinds_run_under_sharded_engine(task, kind, kw):
     assert sh.final_acc == pytest.approx(sim.final_acc, abs=1e-6)
 
 
-class _StopAfterCheckpoint(eng.Callback):
-    """Simulates a crash right after a snapshot lands on disk."""
-
-    def on_checkpoint(self, ev):
-        raise eng.StopRun
-
-
 def test_checkpoint_resume_reproduces_history(task, tmp_path):
-    full = _experiment(task, rounds=8).run()
+    full = experiment(task, rounds=8).run()
 
     ckpt = str(tmp_path / "ckpt")
-    interrupted = (_experiment(task, rounds=8)
+    interrupted = (experiment(task, rounds=8)
                    .with_checkpoint(ckpt, every=3)
-                   .with_callbacks(_StopAfterCheckpoint())
+                   .with_callbacks(StopAfterCheckpoint())
                    .run())
     assert len(interrupted.history) == 3        # stopped at the round-3 save
     assert os.path.exists(os.path.join(ckpt, "state-r3.npz"))
@@ -212,7 +169,7 @@ def test_resume_without_remaining_rounds_is_stable(task, tmp_path):
     still reports the saved history and accuracy — and comes back on the
     engine backend the run was saved under."""
     ckpt = str(tmp_path / "ckpt")
-    full = (_experiment(task, rounds=3)
+    full = (experiment(task, rounds=3)
             .with_engine("sharded", rounds_per_call=2)
             .with_checkpoint(ckpt, every=3).run())
     exp = Experiment.resume(ckpt)
@@ -261,7 +218,7 @@ def test_stoprun_mid_round_keeps_state_consistent(task):
             if ev.round + 1 >= self.n:
                 raise eng.StopRun
 
-    res = _experiment(task, rounds=8).with_callbacks(StopAfter(3)).run()
+    res = experiment(task, rounds=8).with_callbacks(StopAfter(3)).run()
     assert len(res.history) == 3
     assert res.ledger.rounds == 3
     assert [h["round"] for h in res.history] == [0, 1, 2]
@@ -323,10 +280,10 @@ def test_hetlora_weighted_aggregation_math():
                                cov / 4.0, atol=0)
 
 
-def test_hetlora_weighted_changes_round_outputs(task):
-    base = KIND_KWARGS["hetlora"]
-    res_plain = _experiment(task, "hetlora", **base).run()
-    res_w = _experiment(task, "hetlora", hetlora_weighted=True, **base).run()
+def test_hetlora_weighted_changes_round_outputs(task, sim_run):
+    res_plain, _ = sim_run("hetlora")
+    res_w = experiment(task, "hetlora", hetlora_weighted=True,
+                       **KIND_KWARGS["hetlora"]).run()
     # identical communication, different server trajectory
     assert res_w.ledger.total_bytes == res_plain.ledger.total_bytes
     assert any(a["loss"] != b["loss"]

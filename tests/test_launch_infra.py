@@ -97,7 +97,7 @@ def test_hloprof_unparseable_dot_raises():
 
 def test_hloprof_bf16_upcast_detection():
     """CPU materializes f32 copies of bf16 dot inputs; cpu_upcast_bytes must
-    see them (the old wrapped_convert fusion naming no longer exists)."""
+    see them, whether or not the fusion call prints its operand types."""
     def g(x, w):
         return (x @ w).astype(jnp.bfloat16)
     args = (jax.ShapeDtypeStruct((64, 64), jnp.bfloat16),
@@ -106,6 +106,33 @@ def test_hloprof_bf16_upcast_detection():
     up = hloprof.cpu_upcast_bytes(c.as_text())
     # at least the two 64x64 f32 operand upcasts
     assert up >= 2 * 64 * 64 * 4
+
+
+INLINE_TYPED_UPCAST_HLO = """
+%parallel_convert (p: bf16[8,16]) -> f32[8,16] {
+  %p = bf16[8,16]{1,0} parameter(0)
+  ROOT %c = f32[8,16]{1,0} convert(bf16[8,16]{1,0} %p)
+}
+%softmax (q: bf16[8,16]) -> f32[8,16] {
+  %q = bf16[8,16]{1,0} parameter(0)
+  ROOT %e = f32[8,16]{1,0} convert(bf16[8,16]{1,0} %q)
+}
+ENTRY %main (x: bf16[8,16], w: bf16[8,16]) -> f32[8,16] {
+  %x = bf16[8,16]{1,0} parameter(0)
+  %w = bf16[8,16]{1,0} parameter(1)
+  %up = f32[8,16]{1,0} call(bf16[8,16]{1,0} %x), to_apply=%parallel_convert
+  %cv = f32[8,16]{1,0} convert(bf16[8,16]{1,0} %w)
+  %sm = f32[8,16]{1,0} fusion(bf16[8,16]{1,0} %w), kind=kLoop, calls=%softmax
+  ROOT %add = f32[8,16]{1,0} add(f32[8,16]{1,0} %up, f32[8,16]{1,0} %cv)
+}
+"""
+
+
+def test_hloprof_bf16_upcast_detection_inline_typed_operands():
+    """Printers that type each call operand inline: the convert wrapper and
+    the plain convert count, and a non-convert fusion that widens bf16 does
+    not."""
+    assert hloprof.cpu_upcast_bytes(INLINE_TYPED_UPCAST_HLO) == 2 * 8 * 16 * 4
 
 
 def test_dryrun_sanity_check():

@@ -22,6 +22,7 @@ import json
 import numpy as np
 import pytest
 
+from _fedexp import MODEL, PRETRAIN_STEPS, backbone
 from repro.data import datasets as ds
 from repro.federated import engine as eng
 from repro.federated import population as popn
@@ -41,10 +42,11 @@ def _experiment(task, rounds=4, **spec_kw):
     return (Experiment(task)
             .with_strategy("flasc", **defaults)
             .with_federation(n_clients=4, local_batch=4, local_steps=2)
-            .with_model(d_model=16, num_layers=1, num_heads=2, d_ff=32)
+            .with_model(**MODEL)
             .with_lora(rank=4)
-            .with_training(rounds=rounds, pretrain_steps=2, eval_every=2,
-                           seed=0))
+            .with_training(rounds=rounds, pretrain_steps=PRETRAIN_STEPS,
+                           eval_every=2, seed=0)
+            .with_params(*backbone(task)))
 
 
 def _losses(res):

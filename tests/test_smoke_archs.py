@@ -1,39 +1,16 @@
 """Per-architecture smoke tests: reduced variant (<=2 layers per group,
 d_model<=512, <=4 experts), one forward/train step on CPU, asserting output
-shapes and no NaNs — plus a prefill+decode step for every arch."""
+shapes and no NaNs.  The prefill+decode step for every arch is in
+test_smoke_decode.py."""
 import jax
 import jax.numpy as jnp
 import pytest
 
+from _archs import built, make_batch  # noqa: F401  (built: module fixture)
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.models import lora as lora_mod
 from repro.models import model as mdl
 from repro.models.config import LoRAConfig
-from repro.models.layers import init_params
-
-
-def make_batch(cfg, B=2, S=16, key=None):
-    key = key or jax.random.key(7)
-    b = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab_size)}
-    if cfg.encoder_decoder:
-        b["frames"] = jax.random.normal(key, (B, cfg.encoder_seq, cfg.d_model)) * 0.1
-    if cfg.num_image_tokens > 0:
-        b["image_embeds"] = jax.random.normal(
-            key, (B, cfg.num_image_tokens, cfg.vision_embed_dim)) * 0.1
-    return b
-
-
-@pytest.fixture(scope="module")
-def built():
-    cache = {}
-
-    def get(arch):
-        if arch not in cache:
-            cfg = get_config(arch, smoke=True)
-            params = init_params(mdl.model_spec(cfg), jax.random.key(0))
-            cache[arch] = (cfg, params)
-        return cache[arch]
-    return get
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -70,55 +47,6 @@ def test_forward_and_train_step(built, arch):
     assert jnp.isfinite(loss2)
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
-def test_prefill_decode(built, arch):
-    cfg, params = built(arch)
-    B, S = 2, 16
-    batch = make_batch(cfg, B, S)
-    logits, cache = mdl.prefill(params, cfg, batch)
-    assert logits.shape == (B, 1, cfg.vocab_size)
-    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-    lg, cache2 = mdl.decode_step(params, cfg, tok, jnp.asarray(S), cache)
-    assert lg.shape == (B, 1, cfg.vocab_size)
-    assert not bool(jnp.isnan(lg).any())
-    assert jax.tree.structure(cache2) == jax.tree.structure(cache)
-
-
-@pytest.mark.parametrize("arch", [
-    "minitron-8b", "qwen3-32b",
-    # deepseek-v2 historically drifted past the 2e-2 budget; root cause was
-    # call-size-dependent MoE expert capacity (not MLA): forward/prefill/
-    # decode saw different capacities and dropped different assignments.
-    # Fixed in models/moe.py by anchoring capacity to the design group size.
-    "deepseek-v2-236b",
-])
-def test_decode_matches_forward(built, arch):
-    """Teacher-forced decode at position S must reproduce the forward logits
-    at position S (same cache semantics, absolute rope)."""
-    cfg, params = built(arch)
-    B, S = 2, 12
-    key = jax.random.key(3)
-    toks = jax.random.randint(key, (B, S + 1), 0, cfg.vocab_size)
-    full = mdl.forward(params, cfg, {"tokens": toks})["logits"]
-    _, cache = mdl.prefill(params, cfg, {"tokens": toks[:, :S]}, max_len=S + 1)
-    lg, _ = mdl.decode_step(params, cfg, toks[:, S], jnp.asarray(S), cache)
-    err = float(jnp.max(jnp.abs(lg[:, 0] - full[:, S])))
-    assert err < 2e-2, f"{arch}: decode/forward mismatch {err}"
-
-
-def test_sliding_window_decode_matches_forward(built):
-    cfg, params = built("minitron-8b")
-    B, S, W = 1, 12, 8
-    toks = jax.random.randint(jax.random.key(4), (B, S + 1), 0, cfg.vocab_size)
-    full = mdl.forward(params, cfg, {"tokens": toks}, window=W)["logits"]
-    _, cache = mdl.prefill(params, cfg, {"tokens": toks[:, :S]}, window=W,
-                           max_len=S + 1)
-    lg, _ = mdl.decode_step(params, cfg, toks[:, S], jnp.asarray(S), cache,
-                            window=W)
-    err = float(jnp.max(jnp.abs(lg[:, 0] - full[:, S])))
-    assert err < 2e-2, f"sliding-window decode mismatch {err}"
-
-
 def test_param_counts_match_assignment():
     import repro.models.model as M
     # full configs should land near their nameplate sizes
@@ -132,28 +60,3 @@ def test_param_counts_match_assignment():
     for arch, (lo, hi) in approx.items():
         n = M.count_params(get_config(arch))
         assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B outside [{lo/1e9},{hi/1e9}]"
-
-
-@pytest.mark.parametrize("arch", ["yi-9b", "hymba-1.5b", "xlstm-1.3b",
-                                  "whisper-large-v3"])
-def test_multistep_decode_matches_forward(built, arch):
-    """Teacher-forced multi-step decode must track forward logits at every
-    position (catches cache-slot/rolling-buffer bugs across steps)."""
-    cfg, params = built(arch)
-    B, S, G = 2, 8, 4
-    key = jax.random.key(11)
-    toks = jax.random.randint(key, (B, S + G), 0, cfg.vocab_size)
-    batch_full = {"tokens": toks}
-    batch_pre = {"tokens": toks[:, :S]}
-    if cfg.encoder_decoder:
-        frames = jax.random.normal(key, (B, cfg.encoder_seq, cfg.d_model)) * 0.1
-        batch_full["frames"] = frames
-        batch_pre["frames"] = frames
-    full = mdl.forward(params, cfg, batch_full)["logits"]
-    _, cache = mdl.prefill(params, cfg, batch_pre, max_len=S + G)
-    errs = []
-    for i in range(G):
-        lg, cache = mdl.decode_step(params, cfg, toks[:, S + i],
-                                    jnp.asarray(S + i), cache)
-        errs.append(float(jnp.max(jnp.abs(lg[:, 0] - full[:, S + i]))))
-    assert max(errs) < 3e-2, f"{arch}: stepwise decode drift {errs}"
