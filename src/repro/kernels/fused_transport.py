@@ -57,21 +57,24 @@ owns that dispatch).  The TPU compiler takes no scatter and no block
 below the (8, 128) tiling, so every stream is viewed as (rows, 128)
 tiles, scalars (threshold, scale, hi0, the running pack offset) sit in
 SMEM, and per-block reductions leave as (1, 128) partials.  The bin
-histogram is an MXU product of one-hot high and low bin bits; the pack
-compacts each tile row with a lane-shift network and writes the rows at
-the running offset (`_pack_tile`).  tests/test_tpu_compile.py compiles
-every kernel for a described v5e chip at GPT-2 Small's sizes.  The
-single-vector pack variant accumulates its packed outputs across the
-sequential grid via `pl.program_id(0)`, so it must not be vmapped;
-batched callers (the engines' cohort pack step) use
-`pack_values_batch`, whose 2-D-grid kernel gives every batch row its
-*own* accumulator block — per-row init at the row's first grid step —
-and is bit-identical to `jax.vmap(pack_values)` by construction
+histogram is one MXU product per `COUNT_ROWS` tile rows, laid end to
+end along the lanes: one-hots of their high and low bin bits, exact
+(0/1 in bf16, at most `COUNT_ROWS * 128` per product in f32, products
+summed in int32).  The pack compacts each tile row with a lane-shift
+network and writes the rows at the running offset (`_pack_tile`).
+tests/test_tpu_compile.py compiles every kernel for a described v5e
+chip at GPT-2 Small's sizes.  The single-vector pack variant accumulates
+its packed outputs across the sequential grid via `pl.program_id(0)`,
+so it must not be vmapped; batched callers (the engines' cohort pack
+step) use `pack_values_batch`, whose 2-D-grid kernel gives every batch
+row its *own* accumulator block — per-row init at the row's first grid
+step — and is bit-identical to `jax.vmap(pack_values)` by construction
 (pinned in tests/test_fused_transport.py).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +86,7 @@ from repro.kernels.topk_mask import (BLOCK, LANES, SMEM, check_stream,
                                      tiles)
 
 LEVELS = 12         # default bisection depth: 2^12 magnitude bins (16 KiB)
+COUNT_ROWS = 32     # tile rows whose bins one MXU product counts
 
 
 # ---------------------------------------------------------------------------
@@ -128,24 +132,28 @@ def _bin_kernel(levels, s_ref, x_ref, hist_ref, idx_ref):
         hi = jnp.where(up, hi, mid)
     idx_ref[...] = idx
     # bincount without a scatter: bin = (h, l) splits into its high and
-    # low bits, and hist[h, l] = sum over elements of onehot_h * onehot_l —
-    # one (nh, 128) x (nl, 128)^T MXU product per tile row.  The one-hots
-    # are exact in bf16 and a row's counts (<= 128) in f32; rows add up
-    # in int32.
+    # low bits, and hist[h, l] = sum over elements of onehot_h * onehot_l.
+    # `r` tile rows are laid end to end along the lanes, and one
+    # (nh, r*128) x (nl, r*128)^T MXU product counts them all; the
+    # products do not wait on each other, only their int32 sum is carried.
+    # Exact: the one-hots are 0/1 in bf16, a product's counts (<= r*128)
+    # sit below 2^24 in f32, and the products add up in int32.
     nh, nl = hist_ref.shape
     lo_bits = nl.bit_length() - 1
-    hs = jax.lax.broadcasted_iota(jnp.int32, (nh, LANES), 0)
-    ls = jax.lax.broadcasted_iota(jnp.int32, (nl, LANES), 0)
+    rows = a.shape[0]
+    r = math.gcd(rows, COUNT_ROWS)      # interpret blocks: any row count
+    hs = jax.lax.broadcasted_iota(jnp.int32, (nh, r * LANES), 0)
+    ls = jax.lax.broadcasted_iota(jnp.int32, (nl, r * LANES), 0)
 
-    def row(r, acc):
-        v = idx_ref[pl.ds(r, 1), :]
+    def count(i, acc):
+        v = idx_ref[pl.ds(pl.multiple_of(i * r, r), r), :].reshape(1, -1)
         oh = jnp.where((v >> lo_bits) == hs, 1.0, 0.0).astype(jnp.bfloat16)
         ol = jnp.where((v & (nl - 1)) == ls, 1.0, 0.0).astype(jnp.bfloat16)
         return acc + jax.lax.dot_general(
             oh, ol, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32).astype(jnp.int32)
 
-    hist_ref[...] = jax.lax.fori_loop(0, a.shape[0], row,
+    hist_ref[...] = jax.lax.fori_loop(0, rows // r, count,
                                       jnp.zeros((nh, nl), jnp.int32))
 
 

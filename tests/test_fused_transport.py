@@ -73,6 +73,69 @@ def test_threshold_from_bins_matches_histogram_bisection(n, seed):
 
 
 # ---------------------------------------------------------------------------
+# bin histogram: the kernel == a plain path replay + bincount, bit for bit
+# ---------------------------------------------------------------------------
+
+def _bins_reference(x, hi0, levels):
+    """Every element's `levels`-step bisection path from (0, hi0), as the
+    kernel replays it, then `jnp.bincount` of the path indices."""
+    a = jnp.abs(x.astype(jnp.float32))
+    lo = jnp.zeros_like(a)
+    hi = jnp.full_like(a, hi0)
+    idx = jnp.zeros(a.shape, jnp.int32)
+    for _ in range(levels):
+        mid = 0.5 * (lo + hi)
+        up = a >= mid
+        idx = idx * 2 + up.astype(jnp.int32)
+        lo = jnp.where(up, mid, lo)
+        hi = jnp.where(up, hi, mid)
+    return jnp.bincount(idx, length=1 << levels)
+
+
+def _assert_bins(x, hi0, levels=LEVELS, block=None):
+    n = x.shape[-1]
+    got = ft.bin_counts_pallas(x, hi0, levels, block=block or n,
+                               interpret=True)
+    want = _bins_reference(x, hi0, levels)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(got.sum()) == n
+
+
+@pytest.mark.parametrize("block", [384, 1024, 4096, 12288])
+def test_bin_counts_multiblock_grid_matches_reference(block):
+    """12,288 entries cut into 32, 12, 3 and 1 blocks of 3 to 96 rows."""
+    x = _vec(12288, 21)
+    _assert_bins(x, jnp.max(jnp.abs(x)), block=block)
+
+
+def test_bin_counts_vmapped_clients_match_reference():
+    """Clients of different scales, each binned against its own `hi0`
+    (one of them above its absmax), as the engines' vmapped uploads."""
+    X = jax.random.normal(jax.random.key(22), (3, 4096))
+    X = X * jnp.asarray([1.0, 1e-3, 40.0])[:, None]
+    hi0 = jnp.max(jnp.abs(X), axis=1) * jnp.asarray([1.0, 1.0, 1.5])
+    got = jax.vmap(lambda x, h: ft.bin_counts_pallas(
+        x, h, LEVELS, block=1024, interpret=True))(X, hi0)
+    want = jax.vmap(lambda x, h: _bins_reference(x, h, LEVELS))(X, hi0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("levels", [LEVELS, 11])
+def test_bin_counts_edges_match_reference(levels):
+    """All zeros (every path runs up: one full top bin), ties on every
+    bin midpoint, and a single nonzero entry; 11 levels give nh != nl."""
+    ties = jnp.arange(4096, dtype=jnp.float32) / 4096.0   # dyadic midpoints
+    one = jnp.zeros((2048,)).at[1234].set(-0.75)
+    for x, hi0 in ((jnp.zeros((1024,)), 0.0), (ties, 1.0), (-ties, 1.0),
+                   (one, 0.75)):
+        _assert_bins(x, jnp.float32(hi0), levels)
+        _assert_bins(x, jnp.float32(hi0), levels, block=256)
+    zeros = ft.bin_counts_pallas(jnp.zeros((1024,)), jnp.float32(0.0),
+                                 levels, block=1024, interpret=True)
+    assert int(zeros[-1]) == 1024
+
+
+# ---------------------------------------------------------------------------
 # selector differential: fused == histogram on every edge
 # ---------------------------------------------------------------------------
 

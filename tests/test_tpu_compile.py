@@ -1,5 +1,5 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a described
-TPU v5e chip, at GPT-2 Small's sizes.
+TPU v5e chip, at GPT-2 Small's sizes (and Yi-9B's for the bin counts).
 
 Interpret mode accepts block shapes and in-kernel ops that the TPU
 compiler refuses (sub-(8, 128) blocks, rank-1 scalar blocks, scatters).
@@ -11,6 +11,8 @@ described, not attached, so a kernel the chip would refuse fails here:
     `bin_counts_pallas`, `fused_mask_quantize_pallas` and
     `topk_mask_pallas` vmapped over the clients (the fused selector),
     `fused_mask_quantize_pack_pallas` and `threshold_count_pallas`;
+    `absmax_pallas` and `bin_counts_pallas` also at the flat length of
+    Yi-9B cut to 8 layers (3,276,800 entries);
   * `pack_values_batched_pallas` at `comm.pack_capacity` for density 0.25
     (the sparse aggregation pack);
   * `grouped_pallas` at K = 768, R = 16 and every N of the model.
@@ -19,6 +21,7 @@ Nothing here runs a kernel: results are pinned by the interpret-mode
 differential tests.  The topology is described inside a fixture, so only
 the worker that runs this file loads the TPU compiler.
 """
+import dataclasses
 import functools
 
 import jax
@@ -27,6 +30,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.paper_models import GPT2_SMALL
+from repro.configs.yi_9b import CONFIG as YI_9B
 from repro.core import comm
 from repro.core import sparsity as sp
 from repro.kernels import fused_transport as ft
@@ -78,8 +82,19 @@ def test_gpt2_small_flat_length(sizes):
     assert sizes["n"] == 12 * 4 * 2 * 768 * 16
 
 
-def test_absmax_and_bin_counts(chip, sizes):
-    b, n = CLIENTS, sizes["n_pad"]
+def _yi9b_8l_flat_length():
+    cfg = dataclasses.replace(YI_9B, num_layers=8)
+    return abstract_flat_meta(cfg, LoRAConfig(rank=16)).p_len
+
+
+@pytest.mark.parametrize("model", ["gpt2-small", "yi-9b-8l"])
+def test_absmax_and_bin_counts(chip, sizes, model):
+    b = CLIENTS
+    if model == "gpt2-small":
+        n = sizes["n_pad"]
+    else:                       # 50 whole blocks: no padding
+        n = _yi9b_8l_flat_length()
+        assert n == 3_276_800 and n % tk.BLOCK == 0
 
     def fn(x):
         hi0 = jax.vmap(ft.absmax_pallas)(x)
