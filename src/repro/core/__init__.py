@@ -5,8 +5,7 @@ from repro.core.selectors import (Selector, SelectorLike, register_selector,
                                   registered_selectors, resolve_selector)
 from repro.core.strategies import (Strategy, StrategySpec, RoundPlan,
                                    UploadRule, PlanContext, register_strategy,
-                                   registered_kinds, resolve,
-                                   init_strategy_state)
+                                   registered_kinds, resolve)
 from repro.core.transport import (Message, Pipeline, MaskSparsify,
                                   TopKSparsify, Quantize, LowRankCompress,
                                   register_stage, registered_stages,
@@ -21,7 +20,6 @@ __all__ = ["topk_mask", "topk_mask_by_count", "sparsify", "sparsify_by_count",
            "registered_selectors", "resolve_selector",
            "Strategy", "StrategySpec", "RoundPlan", "UploadRule",
            "PlanContext", "register_strategy", "registered_kinds", "resolve",
-           "init_strategy_state",
            "Message", "Pipeline", "MaskSparsify", "TopKSparsify", "Quantize",
            "LowRankCompress", "register_stage", "registered_stages",
            "download_pipeline", "upload_pipeline", "wire_format",

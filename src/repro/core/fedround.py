@@ -38,13 +38,13 @@ from repro.kernels import fused_transport as ft
 from repro.models.config import FederatedConfig
 from repro.optim import adam_init, adam_update
 
-LossFn = Callable[[Any, Dict[str, jax.Array]], jax.Array]
-# loss_of(trainable_tree, microbatch) -> scalar
+LossFn = Callable[[Any, Any, Dict[str, jax.Array]], jax.Array]
+# loss_of(params, trainable_tree, microbatch) -> scalar: the frozen backbone
+# rides every step as an explicit (shardable, FSDP-able) argument, never as
+# a closed-over replicated constant.
 
-ParamLossFn = Callable[[Any, Any, Dict[str, jax.Array]], jax.Array]
-# loss_of(params, trainable_tree, microbatch) -> scalar — the sharded-params
-# path: the frozen backbone rides the step as an explicit (shardable,
-# FSDP-able) argument instead of a closed-over replicated constant.
+_BoundLoss = Callable[[Any, Dict[str, jax.Array]], jax.Array]
+# a LossFn with its backbone bound: (trainable_tree, microbatch) -> scalar
 
 
 @dataclasses.dataclass
@@ -136,7 +136,7 @@ def _keep_count(p_len: int, density: float) -> int:
 
 
 def _run_clients(P_base, plans, client_batches, s: st.StrategySpec, *,
-                 loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
+                 loss_of: _BoundLoss, meta: FlatMeta, fed: FederatedConfig,
                  kdown=None, upkeys=None, ax_key=None, spmd_axis_name=None,
                  round_idx=None, client_mu=None):
     """Stack per-client `RoundPlan`s onto the vmapped client axis and run
@@ -244,15 +244,54 @@ def _aggregate_uploads(strat: st.Strategy, deltas, ctx):
     return jnp.where(overflow, strat.aggregate(deltas, ctx), acc / n)
 
 
-def federated_round(flatP, server_state, sstate, client_batches, rng, *,
-                    loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
-                    strategy: Optional[st.StrategyLike] = None,
-                    spec: Optional[st.StrategySpec] = None,
-                    spmd_axis_name=None, client_mu=None, params=None):
+def _bind_backbone(loss_of: LossFn, params) -> _BoundLoss:
+    """`loss_of` with the frozen backbone bound, for the client block.
+
+    ZeRO-3 semantics: the backbone is *stored* sharded between rounds (the
+    jit's FSDP/TP in_shardings) and gathered to full replicas at use, so
+    every client's forward/backward computes on local full weights — which
+    also keeps the sharded program bit-identical to the single-device one
+    (no-op without an activation_sharding context, i.e. on SimEngine and
+    the AsyncEngine)."""
+    from repro.launch.shardings import gather_replicated
+    return functools.partial(loss_of, gather_replicated(params))
+
+
+def _server_update(strat: st.Strategy, fed: FederatedConfig, flatP,
+                   server_state, sstate, pseudo_grad, *, P_base, m_down,
+                   round_idx, ctx):
+    """The server tail of a round: FedAdam, or the FedAvg/FedSGD rule
+    (paper Appendix A: W <- W - lr * mean(delta)), on the aggregated
+    pseudo-gradient, then the strategy's `post_round` hook.  The
+    synchronous round and the split round's server phase both end here,
+    which is what keeps the sim == async anchor bit-exact.
+
+    Returns (flatP', server_state', sstate')."""
+    if fed.server_opt == "adam":
+        flatP, opt = adam_update(flatP, pseudo_grad, server_state["opt"],
+                                 fed.server_lr, fed.adam_b1, fed.adam_b2,
+                                 fed.adam_eps)
+    else:
+        flatP = flatP - fed.server_lr * pseudo_grad
+        opt = server_state["opt"]
+    sstate, flatP = st.call_post_round(strat, sstate, flatP, P_base=P_base,
+                                       m_down=m_down, round_idx=round_idx,
+                                       ctx=ctx)
+    return flatP, {"opt": opt, "round": round_idx + 1}, sstate
+
+
+def federated_round(params, flatP, server_state, sstate, client_batches, rng,
+                    *, loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
+                    strategy: st.StrategyLike, spmd_axis_name=None,
+                    client_mu=None):
     """One round. client_batches leaves: (n_clients, local_steps, local_bs, ...).
 
-    `strategy` accepts a `Strategy` instance, a `StrategySpec`, or a kind
-    string (`spec` is the legacy alias).  `spmd_axis_name` (e.g. ('data',)
+    `params` is the frozen backbone pytree, which `loss_of(params, tree,
+    mb)` reads.  It must be a *traced argument* of the enclosing jit, so
+    FSDP/TP in_shardings apply to the backbone instead of baking it in as
+    a replicated constant (docs/engines.md); a task with no backbone
+    passes `{}`.  `strategy` accepts a `Strategy` instance, a
+    `StrategySpec`, or a kind string.  `spmd_axis_name` (e.g. ('data',)
     or ('pod','data')) shards the vmapped client axis across the mesh in
     the production lowering.
     Returns (flatP', server_state', sstate', metrics).
@@ -262,24 +301,9 @@ def federated_round(flatP, server_state, sstate, client_batches, rng, *,
     rows come back as `metrics["client_mu"]` for the engine to scatter
     into the `federated.population` store.  None (the default) keeps the
     stateless zeros-init trace unchanged.
-
-    `params` is the frozen backbone pytree on the sharded-params path:
-    when set, `loss_of` is a `ParamLossFn` taking `(params, tree, mb)`
-    and `params` must be a *traced argument* of the enclosing jit, so
-    FSDP/TP in_shardings apply to the backbone instead of baking it in
-    as a replicated constant (docs/engines.md).  None keeps the legacy
-    two-argument closure contract, trace-identical to before.
     """
-    strat = st.resolve(strategy if strategy is not None else spec)
-    if params is not None:
-        # ZeRO-3 semantics: the backbone is *stored* sharded between
-        # rounds (the jit's FSDP/TP in_shardings) and gathered to full
-        # replicas at use, so every client's forward/backward computes on
-        # local full weights — which also keeps the sharded program
-        # bit-identical to the single-device one (no-op without an
-        # activation_sharding context, i.e. on SimEngine)
-        from repro.launch.shardings import gather_replicated
-        loss_of = functools.partial(loss_of, gather_replicated(params))
+    strat = st.resolve(strategy)
+    loss = _bind_backbone(loss_of, params)
     s = strat.spec
     round_idx = server_state["round"]
     n_clients = jax.tree.leaves(client_batches)[0].shape[0]
@@ -300,7 +324,7 @@ def federated_round(flatP, server_state, sstate, client_batches, rng, *,
         upkeys, ax_key = (qkeys[:-1], 0) if use_keys else (None, None)
 
         out, (m_down_cs, ax_down) = _run_clients(
-            P_base, plans, client_batches, s, loss_of=loss_of, meta=meta,
+            P_base, plans, client_batches, s, loss_of=loss, meta=meta,
             fed=fed, kdown=kdown, upkeys=upkeys, ax_key=ax_key,
             spmd_axis_name=spmd_axis_name, round_idx=round_idx,
             client_mu=client_mu)
@@ -348,18 +372,9 @@ def federated_round(flatP, server_state, sstate, client_batches, rng, *,
             pseudo_grad = _aggregate_uploads(strat, deltas, ctx)
 
     with jax.named_scope("fedround.server_update"):
-        if fed.server_opt == "adam":
-            flatP, opt = adam_update(flatP, pseudo_grad, server_state["opt"],
-                                     fed.server_lr, fed.adam_b1, fed.adam_b2,
-                                     fed.adam_eps)
-        else:   # FedAvg/FedSGD rule (paper Appendix A): W <- W - lr * mean(delta)
-            flatP = flatP - fed.server_lr * pseudo_grad
-            opt = server_state["opt"]
-
-        sstate, flatP = st.call_post_round(strat, sstate, flatP, P_base=P_base,
-                                           m_down=m_down_global,
-                                           round_idx=round_idx, ctx=ctx)
-        server_state = {"opt": opt, "round": round_idx + 1}
+        flatP, server_state, sstate = _server_update(
+            strat, fed, flatP, server_state, sstate, pseudo_grad,
+            P_base=P_base, m_down=m_down_global, round_idx=round_idx, ctx=ctx)
 
         metrics = {
             "loss": jnp.mean(losses),
@@ -382,96 +397,52 @@ def federated_round(flatP, server_state, sstate, client_batches, rng, *,
 
 
 def make_round_fn(loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
-                  strategy: st.StrategyLike, spmd_axis_name=None, *,
-                  with_params: bool = False):
+                  strategy: st.StrategyLike, spmd_axis_name=None):
     """jit-ready closure over the static pieces; `strategy` may be a
-    Strategy, StrategySpec, or kind string.
+    Strategy, StrategySpec, or kind string.  The returned function takes
+    the frozen backbone as its leading argument —
 
-    `with_params=True` selects the sharded-params contract: `loss_of` is a
-    `ParamLossFn` and the returned function takes the frozen backbone as
-    its leading argument —
-
-        fn(params, flatP, server_state, sstate, client_batches, rng)
+        pfn(params, flatP, server_state, sstate, client_batches, rng,
+            client_mu=None) -> (flatP', server_state', sstate', metrics)
 
     — so a jit over it can shard (FSDP/TP) and audit the backbone like
     any other operand instead of replicating it as a baked-in constant.
+
+    `client_mu` is the population round's operand (docs/scale.md): the
+    (cohort, p_len) gather of the sampled clients' persistent momentum
+    rows, which the engine stages from the `federated.population` store;
+    the post-round rows ride back in `metrics["client_mu"]` for the
+    scatter commit.  Left out, the traced program is the plain
+    stateless round; a cohort whose rows are all zeros computes the
+    plain round's values bit for bit.
     """
     strat = st.resolve(strategy)
 
-    if with_params:
-        def pfn(params, flatP, server_state, sstate, client_batches, rng):
-            return federated_round(flatP, server_state, sstate,
-                                   client_batches, rng, loss_of=loss_of,
-                                   meta=meta, fed=fed, strategy=strat,
-                                   spmd_axis_name=spmd_axis_name,
-                                   params=params)
-        return pfn
-
-    def fn(flatP, server_state, sstate, client_batches, rng):
-        return federated_round(flatP, server_state, sstate, client_batches,
-                               rng, loss_of=loss_of, meta=meta, fed=fed,
-                               strategy=strat, spmd_axis_name=spmd_axis_name)
-    return fn
-
-
-def make_population_round_fn(loss_of: LossFn, meta: FlatMeta,
-                             fed: FederatedConfig, strategy: st.StrategyLike,
-                             spmd_axis_name=None, *,
-                             with_params: bool = False):
-    """`make_round_fn` with the sampled cohort's persistent per-client
-    momentum rows threaded through (population runs, docs/scale.md):
-
-        fn(flatP, server_state, sstate, client_batches, client_mu, rng)
-            -> (flatP', server_state', sstate', metrics)
-
-    `client_mu` is the (cohort, p_len) gather the engine staged from the
-    `federated.population` store; the post-round rows ride back in
-    `metrics["client_mu"]` for the scatter commit.  Everything else is the
-    synchronous round, op for op — a cohort whose rows are all zeros
-    computes bit-identically to the stateless `make_round_fn` path.
-
-    `with_params=True` prepends the frozen backbone argument exactly like
-    `make_round_fn`: fn(params, flatP, server, sstate, batches, client_mu,
-    rng).
-    """
-    strat = st.resolve(strategy)
-
-    if with_params:
-        def pfn(params, flatP, server_state, sstate, client_batches,
-                client_mu, rng):
-            return federated_round(flatP, server_state, sstate,
-                                   client_batches, rng, loss_of=loss_of,
-                                   meta=meta, fed=fed, strategy=strat,
-                                   spmd_axis_name=spmd_axis_name,
-                                   client_mu=client_mu, params=params)
-        return pfn
-
-    def fn(flatP, server_state, sstate, client_batches, client_mu, rng):
-        return federated_round(flatP, server_state, sstate, client_batches,
-                               rng, loss_of=loss_of, meta=meta, fed=fed,
-                               strategy=strat, spmd_axis_name=spmd_axis_name,
+    def pfn(params, flatP, server_state, sstate, client_batches, rng,
+            client_mu=None):
+        return federated_round(params, flatP, server_state, sstate,
+                               client_batches, rng, loss_of=loss_of,
+                               meta=meta, fed=fed, strategy=strat,
+                               spmd_axis_name=spmd_axis_name,
                                client_mu=client_mu)
-    return fn
+    return pfn
 
 
-def make_scanned_round_fn(round_fn, *, with_params: bool = False):
-    """Scan-chunked round driver: runs `round_fn` over a leading rounds axis
-    in one device call, amortizing host dispatch (ShardedEngine's
-    `rounds_per_call`).
+def make_scanned_round_fn(round_fn):
+    """Scan-chunked round driver: runs `round_fn` (a `make_round_fn`
+    result) over a leading rounds axis in one device call, amortizing host
+    dispatch (ShardedEngine's `rounds_per_call`).
 
-    The returned function takes (flatP, server, sstate, batches, round_ids,
-    base_key) where every `batches` leaf has an extra leading rounds axis,
-    `round_ids` is the (k,) int32 vector of global round indices, and each
-    round's rng is derived as fold_in(base_key, round_id) — bit-identical to
-    the per-round driver's key schedule.  Metrics come back stacked along
-    the rounds axis.
-
-    `with_params=True` expects a sharded-params `round_fn` and prepends
-    the backbone argument: fn(params, flatP, server, sstate, batches,
-    round_ids, base_key).  The backbone is scan-invariant — it enters the
-    loop as a constant carry-free operand, so the k chunked rounds reuse
-    one sharded copy instead of re-transferring it per round (the
-    dispatch-savings scan `benchmarks/sharded_bench.py` measures).
+    The returned function takes (params, flatP, server, sstate, batches,
+    round_ids, base_key) where every `batches` leaf has an extra leading
+    rounds axis, `round_ids` is the (k,) int32 vector of global round
+    indices, and each round's rng is derived as fold_in(base_key,
+    round_id) — bit-identical to the per-round driver's key schedule.
+    Metrics come back stacked along the rounds axis.  The backbone is
+    scan-invariant — it enters the loop as a constant carry-free operand,
+    so the k chunked rounds reuse one sharded copy instead of
+    re-transferring it per round (the dispatch-savings scan
+    `benchmarks/sharded_bench.py` measures).
     """
 
     def scan_rounds(params, flatP, server_state, sstate, batches, round_ids,
@@ -480,18 +451,15 @@ def make_scanned_round_fn(round_fn, *, with_params: bool = False):
             flatP, server_state, sstate = carry
             cb, rid = xs
             key = jax.random.fold_in(base_key, rid)
-            args = (flatP, server_state, sstate, cb, key)
-            flatP, server_state, sstate, m = (
-                round_fn(params, *args) if with_params else round_fn(*args))
+            flatP, server_state, sstate, m = round_fn(
+                params, flatP, server_state, sstate, cb, key)
             return (flatP, server_state, sstate), m
 
         (flatP, server_state, sstate), metrics = jax.lax.scan(
             body, (flatP, server_state, sstate), (batches, round_ids))
         return flatP, server_state, sstate, metrics
 
-    if with_params:
-        return scan_rounds
-    return functools.partial(scan_rounds, None)
+    return scan_rounds
 
 
 # ---------------------------------------------------------------------------
@@ -503,20 +471,15 @@ def make_scanned_round_fn(round_fn, *, with_params: bool = False):
 def make_client_phase_fn(loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
                          strategy: st.StrategyLike, slots: Tuple[int, ...],
                          repeats: Optional[Tuple[int, ...]] = None,
-                         pack_cap: Optional[int] = None, *,
-                         with_params: bool = False):
+                         pack_cap: Optional[int] = None):
     """Client side of the split round: run the cohort slots in `slots`
     (a static tuple of global client indices) against one server snapshot.
 
-    The returned function has signature
-
-        fn(flatP, sstate, round_idx, client_batches, rng)
-            -> (deltas, up_nnzs, losses, down_nnzs)
-
-    or, with `with_params=True` (the sharded-params contract, lockstep
-    with `make_round_fn`), the frozen backbone leads the argument list —
+    The returned function takes the frozen backbone first, like
+    `make_round_fn`'s —
 
         fn(params, flatP, sstate, round_idx, client_batches, rng)
+            -> (deltas, up_nnzs, losses, down_nnzs)
 
     or, with `pack_cap` set (the AsyncEngine sparse-aggregation path),
 
@@ -548,8 +511,7 @@ def make_client_phase_fn(loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
     assert len(repeats) == len(slots), (slots, repeats)
 
     def phase(params, flatP, sstate, round_idx, client_batches, rng):
-        loss = (loss_of if params is None
-                else functools.partial(loss_of, params))
+        loss = _bind_backbone(loss_of, params)
         with jax.named_scope("fedround.clients"):
             m_down_global = strat.download_mask(flatP, sstate, round_idx)
             P_base = strat.download_base(flatP, sstate)
@@ -579,10 +541,7 @@ def make_client_phase_fn(loss_of: LossFn, meta: FlatMeta, fed: FederatedConfig,
                 idx, val, pnnz = ft.pack_values_batch(deltas, pack_cap)
             return deltas, nnzs, losses, down_nnzs, idx, val, pnnz
         return deltas, nnzs, losses, down_nnzs
-
-    if with_params:
-        return phase
-    return functools.partial(phase, None)
+    return phase
 
 
 def make_server_phase_fn(meta: FlatMeta, fed: FederatedConfig,
@@ -649,17 +608,7 @@ def make_server_phase_fn(meta: FlatMeta, fed: FederatedConfig,
                 pseudo_grad = strat.aggregate(deltas * weights[:, None], ctx)
 
         with jax.named_scope("fedround.server_update"):
-            if fed.server_opt == "adam":
-                flatP2, opt = adam_update(flatP, pseudo_grad,
-                                          server_state["opt"], fed.server_lr,
-                                          fed.adam_b1, fed.adam_b2,
-                                          fed.adam_eps)
-            else:   # FedAvg/FedSGD rule (paper Appendix A)
-                flatP2 = flatP - fed.server_lr * pseudo_grad
-                opt = server_state["opt"]
-
-            sstate2, flatP2 = st.call_post_round(strat, sstate, flatP2,
-                                                 P_base=P_base, m_down=m_down,
-                                                 round_idx=round_idx, ctx=ctx)
-        return flatP2, {"opt": opt, "round": round_idx + 1}, sstate2
+            return _server_update(strat, fed, flatP, server_state, sstate,
+                                  pseudo_grad, P_base=P_base, m_down=m_down,
+                                  round_idx=round_idx, ctx=ctx)
     return fn

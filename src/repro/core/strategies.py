@@ -712,12 +712,3 @@ def _ortho_lora_pairs(tree):
     if isinstance(tree, dict):
         return {k: _ortho_lora_pairs(v) for k, v in tree.items()}
     return tree
-
-
-# ---------------------------------------------------------------------------
-# legacy functional surface (kept for callers that predate the registry)
-# ---------------------------------------------------------------------------
-
-def init_strategy_state(spec: StrategyLike, p_len: int):
-    """Legacy alias for `resolve(spec).init_state(p_len)`."""
-    return resolve(spec).init_state(p_len)

@@ -299,9 +299,9 @@ class Experiment:
         params, cfg = self.build_backbone()
         trainable, meta, scale = self._build_trainable(params, cfg)
 
-        # sharded-params path (docs/engines.md): the backbone enters every
-        # engine step as its leading argument instead of a closure capture,
-        # so a ShardedEngine can apply TRAIN_RULES/FSDP in_shardings to it
+        # the backbone enters every engine step as its leading argument
+        # (docs/engines.md), so a ShardedEngine can apply TRAIN_RULES/FSDP
+        # in_shardings to it
         def loss_of(bb, tree, mb):
             if t.full_finetune:
                 return rt.task_loss(tree["backbone"], cfg, mb)

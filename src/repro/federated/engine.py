@@ -89,17 +89,19 @@ class RoundTask:
     """The static facets of one experiment — what an engine compiles (the
     `plan` argument of `Engine.compile(plan)`).
 
-    loss_of  — with `params=None` (legacy):
-               `loss_of(trainable_tree, microbatch) -> scalar`, closing
-               over the frozen backbone params (which then enter every
-               compiled step as replicated constants).  With `params`
-               set (the sharded-params path): a `fedround.ParamLossFn`,
+    loss_of  — a `fedround.LossFn`,
                `loss_of(params, trainable_tree, microbatch) -> scalar`.
     meta     — `fedround.FlatMeta` for the trainable tree: treedef, leaf
                shapes, the flat length `p_len`, and the LoRA rank/is-B
                index maps strategies use for structured masks.
     fed      — federation geometry + client/server optimizer settings.
     strategy — the *resolved* `Strategy` instance (not a spec/kind).
+    params   — the frozen backbone pytree, passed as the leading step
+               argument by every engine (never donated: the same
+               buffers feed every round).  This is what lets the
+               ShardedEngine apply TRAIN_RULES/FSDP in_shardings to the
+               backbone so the big `configs/` entries fit a pod mesh
+               (docs/engines.md "Sharded backbone params").
     seed     — base rng seed; engines derive per-round keys as
                `fold_in(key(seed + 2), round_idx)`.
     population — optional `population.Population` bundle (host-resident
@@ -109,12 +111,6 @@ class RoundTask:
                sampled out of a population that can be orders of
                magnitude larger, with each client's momentum row
                gathered from / committed back to the host store.
-    params   — the frozen backbone pytree, passed as the leading step
-               argument by every engine (never donated: the same
-               buffers feed every round).  This is what lets the
-               ShardedEngine apply TRAIN_RULES/FSDP in_shardings to the
-               backbone so the big `configs/` entries fit a pod mesh
-               (docs/engines.md "Sharded backbone params").
     param_spec — optional logical-axes `P` spec tree matching `params`
                (e.g. `models.model.model_spec(cfg)`); the ShardedEngine
                translates it through its sharding rules into the
@@ -124,9 +120,9 @@ class RoundTask:
     meta: fedround.FlatMeta
     fed: FederatedConfig
     strategy: st.Strategy
+    params: Any
     seed: int = 0
     population: Optional[popn.Population] = None
-    params: Any = None
     param_spec: Any = None
 
 
@@ -345,24 +341,23 @@ class Engine:
         return {}
 
     def _step_params(self, plan: RoundTask) -> tuple:
-        """Leading step arguments: the frozen backbone on the
-        sharded-params path, nothing on the legacy closure path.  Every
-        engine prepends this to every step call, so the one `RoundTask`
-        switch keeps all backends in signature lockstep.  The
-        ShardedEngine overrides this to place the backbone into its
-        FSDP/TP storage layout once per run."""
-        return () if plan.params is None else (plan.params,)
+        """Leading step arguments: the frozen backbone, which every engine
+        prepends to every step call.  The ShardedEngine overrides this to
+        place the backbone into its FSDP/TP storage layout once per run."""
+        return (plan.params,)
 
     def compile(self, plan: RoundTask):
-        """-> step(flatP, server, sstate, batch, key) ->
-        (flatP', server', sstate', metrics); with `plan.params` set the
-        step takes the backbone first: step(params, flatP, ...)."""
+        """-> step(params, flatP, server, sstate, batch, key,
+        client_mu=None) -> (flatP', server', sstate', metrics).
+        `client_mu` is the population round's (cohort, p_len) momentum
+        gather staged from the host store; with it, `metrics["client_mu"]`
+        carries the finals back for the scatter commit."""
         raise NotImplementedError
 
     def _compile_chunk(self, plan: RoundTask):
-        """-> chunk(flatP, server, sstate, batches, round_ids, base_key),
-        leaves of `batches` carrying a leading rounds axis.  Only engines
-        with rounds_per_call > 1 need this."""
+        """-> chunk(params, flatP, server, sstate, batches, round_ids,
+        base_key), leaves of `batches` carrying a leading rounds axis.
+        Only engines with rounds_per_call > 1 need this."""
         raise NotImplementedError
 
     # --- the one round loop -----------------------------------------------
@@ -416,19 +411,6 @@ class Engine:
         return state
 
     # --- the population round loop -----------------------------------------
-    def compile_population(self, plan: RoundTask):
-        """-> step(flatP, server, sstate, batch, client_mu, key) ->
-        (flatP', server', sstate', metrics) where `client_mu` is the
-        (cohort, p_len) momentum gather staged from the host store and
-        `metrics["client_mu"]` carries the finals back for the scatter
-        commit."""
-        # no donation, like SimEngine.compile: callers snapshot flatP
-        # across calls for the equality anchors
-        return jax.jit(  # reprolint: disable=jit-no-donate -- see above
-            fedround.make_population_round_fn(
-                plan.loss_of, plan.meta, plan.fed, plan.strategy,
-                with_params=plan.params is not None))
-
     def _run_population_rounds(self, state: RunState, data: DataProvider,
                                callbacks: Sequence[Callback] = ()
                                ) -> RunState:
@@ -462,7 +444,7 @@ class Engine:
             pop.store.load_arrays(state.aux["population"])
         pargs = self._step_params(plan)
         base_key = jax.random.key(plan.seed + 2)
-        step = self.compile_population(plan)
+        step = self.compile(plan)
         # always stage through the prefetcher: its cold take() is the
         # same sample+gather+put the inline path would run, and its
         # wait/H2D counters instrument both modes (population_bench.py)
@@ -482,7 +464,7 @@ class Engine:
                             (state.flatP, state.server, state.sstate,
                              metrics) = step(
                                 *pargs, state.flatP, state.server,
-                                state.sstate, batch, mu_dev, key)
+                                state.sstate, batch, key, mu_dev)
                         if pop.prefetch and r + 1 < state.rounds:
                             # the jitted step dispatched asynchronously:
                             # stage round r+1 while round r computes;
@@ -622,8 +604,7 @@ class SimEngine(Engine):
         # callers snapshot flatP across calls for the equality anchors
         return jax.jit(  # reprolint: disable=jit-no-donate -- see above
             fedround.make_round_fn(plan.loss_of, plan.meta,
-                                   plan.fed, plan.strategy,
-                                   with_params=plan.params is not None))
+                                   plan.fed, plan.strategy))
 
 
 class _ShardedStep:
@@ -631,28 +612,27 @@ class _ShardedStep:
     the jit is built on first call and executed under the engine's
     activation-sharding context (required at trace time for `constrain`).
 
-    After the first call, `in_shardings` and `donate_argnums` record what
-    the jit was built with — the multi-device differential suite inspects
-    them (plus the compiled executable's input shardings) to assert that
-    FSDP param sharding actually applied and that the backbone is never
-    donated (tests/test_sharded_multidevice.py)."""
+    The step takes the backbone at position 0, stored under
+    `param_shardings` and never donated; the client batch follows
+    flatP/server/sstate, and trailing operands (the key, the population
+    round's momentum rows) ride replicated.  After the first call,
+    `in_shardings` and `donate_argnums` record what the jit was built
+    with — the multi-device differential suite inspects them (plus the
+    compiled executable's input shardings) to assert that FSDP param
+    sharding actually applied and that the backbone is never donated
+    (tests/test_sharded_multidevice.py)."""
 
     def __init__(self, engine: "ShardedEngine", fn, batch_client_axis: int,
-                 param_shardings=None):
+                 param_shardings):
         self.engine = engine
         self.fn = fn
         self.batch_client_axis = batch_client_axis
-        # None on the legacy closure path; a NamedSharding tree (built by
-        # the engine from plan.param_spec through its rules) when the
-        # step takes the backbone as its leading argument
+        # a NamedSharding tree, built by the engine from plan.param_spec
+        # through its rules
         self.param_shardings = param_shardings
         self.in_shardings = None
         self.donate_argnums: tuple = ()
         self._jitted = None
-
-    @property
-    def has_params(self) -> bool:
-        return self.param_shardings is not None
 
     def _build(self, server, sstate, batch, rest):
         from jax.sharding import NamedSharding, PartitionSpec
@@ -670,26 +650,20 @@ class _ShardedStep:
             return NamedSharding(
                 mesh, logical_to_pspec(x.shape, tuple(axes), mesh, rules))
 
-        shardings = (rep, rep_tree(server), rep_tree(sstate),
-                     jax.tree.map(batch_sharding, batch),
+        shardings = (self.param_shardings, rep, rep_tree(server),
+                     rep_tree(sstate), jax.tree.map(batch_sharding, batch),
                      *(rep_tree(x) for x in rest))
         # flatP/server/sstate are consumed and rebuilt every round; the
         # backbone params are NOT — the same buffers feed every call, so
-        # donating position 0 on the params path would be a
-        # use-after-donate on round 2.  The shift keeps the donated set
-        # exactly {flatP, server, sstate} on both paths.
-        donate = (0, 1, 2) if self.engine.donate else ()
-        if self.has_params:
-            shardings = (self.param_shardings, *shardings)
-            donate = tuple(i + 1 for i in donate)
+        # donating position 0 would be a use-after-donate on round 2
+        donate = (1, 2, 3) if self.engine.donate else ()
         self.in_shardings = shardings
         self.donate_argnums = donate
         return jax.jit(self.fn, in_shardings=shardings, donate_argnums=donate)
 
     def __call__(self, *args):
         from repro.launch.shardings import activation_sharding
-        _, server, sstate, batch, *rest = \
-            args[1:] if self.has_params else args
+        _, _, server, sstate, batch, *rest = args
         if self._jitted is None:
             self._jitted = self._build(server, sstate, batch, rest)
         with activation_sharding(self.engine.mesh, self.engine.rules,
@@ -700,7 +674,7 @@ class _ShardedStep:
 @register_engine("sharded")
 class ShardedEngine(Engine):
     """SPMD backend: the identical round function lowered with
-    jit(in_shardings=..., donate_argnums=(0, 1, 2)) on a device mesh.
+    jit(in_shardings=..., donate_argnums=(1, 2, 3)) on a device mesh.
 
     The vmapped client axis is sharded over the mesh's data(+pod) axes
     (`train_spmd_axes`), activations follow the launch-layer `TRAIN_RULES`,
@@ -712,8 +686,8 @@ class ShardedEngine(Engine):
     (`fedround.make_scanned_round_fn`); chunks are cut at rounds where a
     callback needs host state (eval, checkpoint), so cadences still hold.
 
-    Sharded backbone params: with `plan.params` set, the step takes the
-    frozen backbone as its leading argument (never donated) and its
+    Sharded backbone params: the step takes the frozen backbone as its
+    leading argument (never donated) and its
     *storage* in_shardings come from `plan.param_spec` through
     `param_rules` — on a 2-D client×model mesh the vmapped client axis
     shards over "data" while backbone storage dims shard over "model"
@@ -723,11 +697,8 @@ class ShardedEngine(Engine):
     sharded program is bit-identical to SimEngine — the differential
     anchor tests/test_sharded_multidevice.py holds on a real 8-device
     mesh.  `exact=False` keeps full TP activation sharding (the dry-run
-    lowering), trading the bit-equality anchor for sharded compute.
-    Without `plan.params` the legacy closure path bakes the backbone
-    into the executable as replicated constants — fine at Experiment
-    scale, wrong for the big `configs/` entries (docs/engines.md
-    "Sharded backbone params").
+    lowering), trading the bit-equality anchor for sharded compute
+    (docs/engines.md "Sharded backbone params").
     """
 
     def __init__(self, mesh=None, *, rounds_per_call: int = 1,
@@ -792,12 +763,9 @@ class ShardedEngine(Engine):
         return TRAIN_FSDP_RULES if self.fsdp else TRAIN_RULES
 
     def _param_shardings(self, plan: RoundTask):
-        """NamedSharding tree for the backbone step argument, or None on
-        the legacy closure path.  `plan.param_spec` (logical P axes)
-        translates through `param_rules`; without a spec the backbone
-        replicates."""
-        if plan.params is None:
-            return None
+        """NamedSharding tree for the backbone step argument.
+        `plan.param_spec` (logical P axes) translates through
+        `param_rules`; without a spec the backbone replicates."""
         if plan.param_spec is None:
             from jax.sharding import NamedSharding, PartitionSpec
             rep = NamedSharding(self.mesh, PartitionSpec())
@@ -810,8 +778,6 @@ class ShardedEngine(Engine):
         """Place the backbone into its sharded storage layout ONCE per
         run (matching the step's in_shardings, so no per-round reshard)
         and feed the placed copy to every step call."""
-        if plan.params is None:
-            return ()
         key = id(plan.params)
         if self._placed_params is None or self._placed_params[0] != key:
             placed = jax.device_put(plan.params, self._param_shardings(plan))
@@ -822,8 +788,7 @@ class ShardedEngine(Engine):
         from repro.launch.steps import train_spmd_axes
         return fedround.make_round_fn(plan.loss_of, plan.meta, plan.fed,
                                       plan.strategy,
-                                      spmd_axis_name=train_spmd_axes(self.mesh),
-                                      with_params=plan.params is not None)
+                                      spmd_axis_name=train_spmd_axes(self.mesh))
 
     def compile(self, plan: RoundTask):
         self.last_step = _ShardedStep(
@@ -834,24 +799,8 @@ class ShardedEngine(Engine):
     def _compile_chunk(self, plan: RoundTask):
         self.last_step = _ShardedStep(
             self,
-            fedround.make_scanned_round_fn(
-                self._round_fn(plan),
-                with_params=plan.params is not None),
+            fedround.make_scanned_round_fn(self._round_fn(plan)),
             batch_client_axis=1,
-            param_shardings=self._param_shardings(plan))
-        return self.last_step
-
-    def compile_population(self, plan: RoundTask):
-        from repro.launch.steps import train_spmd_axes
-        # batch sharded over the client axes as usual; the (cohort,
-        # p_len) momentum block and the key ride `rest` replicated
-        self.last_step = _ShardedStep(
-            self,
-            fedround.make_population_round_fn(
-                plan.loss_of, plan.meta, plan.fed, plan.strategy,
-                spmd_axis_name=train_spmd_axes(self.mesh),
-                with_params=plan.params is not None),
-            batch_client_axis=0,
             param_shardings=self._param_shardings(plan))
         return self.last_step
 
@@ -1067,8 +1016,7 @@ class AsyncEngine(Engine):
                 client_fns[key] = jax.jit(  # reprolint: disable=jit-no-donate -- see above
                     fedround.make_client_phase_fn(
                         plan.loss_of, meta, fed, plan.strategy, slots,
-                        repeats, pack_cap=pack_cap or None,
-                        with_params=plan.params is not None))
+                        repeats, pack_cap=pack_cap or None))
             return client_fns[key]
 
         pargs = self._step_params(plan)

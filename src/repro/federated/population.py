@@ -30,9 +30,9 @@ caps the population at what fits on one device.  This module scales the
 The cohort rides the existing round functions unchanged: sampled client
 ids select *state rows* (and, in a deployment, the data shard); the
 vmapped round still sees a (cohort, ...) batch, and
-`fedround.make_population_round_fn` threads the gathered rows through
-the client scan and returns the finals in `metrics["client_mu"]` for the
-scatter-back.  See docs/scale.md.
+the `client_mu` operand of `fedround.make_round_fn` threads the gathered
+rows through the client scan and returns the finals in
+`metrics["client_mu"]` for the scatter-back.  See docs/scale.md.
 """
 from __future__ import annotations
 

@@ -116,16 +116,15 @@ def build_train_step(cfg: ModelConfig, lcfg: LoRAConfig, fed: FederatedConfig,
                      window=None, spmd_axis_name=None):
     """-> train_step(params, flatP, server, sstate, batches, rng) — the
     same params-as-leading-argument shape the engine layer runs
-    (`fedround.make_round_fn(with_params=True)`), so the dry-run lowers
-    exactly the program the ShardedEngine executes."""
+    (`fedround.make_round_fn`), so the dry-run lowers exactly the program
+    the ShardedEngine executes."""
 
     def loss_of(params, lora_tree, mb):
         return mdl.loss_fn(params, cfg, mb, lora=lora_tree,
                            lora_scale=lcfg.scale, window=window)
 
     return fedround.make_round_fn(loss_of, meta, fed, st.resolve(strategy),
-                                  spmd_axis_name=spmd_axis_name,
-                                  with_params=True)
+                                  spmd_axis_name=spmd_axis_name)
 
 
 def train_spmd_axes(mesh):

@@ -52,8 +52,8 @@ def _legacy_run(exp):
     params, cfg = exp.build_backbone()
     trainable, meta, scale = exp._build_trainable(params, cfg)
 
-    def loss_of(tree, mb):
-        p = dict(params)
+    def loss_of(bb, tree, mb):
+        p = dict(bb)
         if "head" in tree:
             p.update(tree["head"])
         return mdl.loss_fn(p, cfg, rt._task_batch(cfg, mb),
@@ -70,7 +70,8 @@ def _legacy_run(exp):
         batch_np = sample_round(task, fed, r, seed=t.seed)
         batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
         key = jax.random.fold_in(jax.random.key(t.seed + 2), r)
-        flatP, server, sstate, m = round_fn(flatP, server, sstate, batch, key)
+        flatP, server, sstate, m = round_fn(params, flatP, server, sstate,
+                                            batch, key)
         down_pm = [float(v) for v in m["down_nnz_clients"]]
         up_pm = [float(v) for v in m["up_nnz_clients"]]
         ledger.record_round(
@@ -191,17 +192,18 @@ def test_weighted_aggregation_refused_under_dp():
                           dp_clip=1.0, dp_noise=0.1)
     spec = st.StrategySpec(kind="hetlora", hetlora_ranks=(2, 4),
                            hetlora_weighted=True)
-    fn = fedround.make_round_fn(lambda tree, mb: jnp.sum(tree["w"]["a"] ** 2),
-                                meta, fed, spec)
+    fn = fedround.make_round_fn(
+        lambda bb, tree, mb: jnp.sum(tree["w"]["a"] ** 2), meta, fed, spec)
     flatP = meta.flatten(tree)
     with pytest.raises(NotImplementedError, match="non-uniform"):
-        fn(flatP, fedround.init_server(flatP), {},
+        fn({}, flatP, fedround.init_server(flatP), {},
            {"x": jnp.zeros((2, 1, 2, 1))}, jax.random.key(0))
     # ...while plain hetlora (uniform averaging) still composes with DP
     spec_ok = st.StrategySpec(kind="hetlora", hetlora_ranks=(2, 4))
     fn_ok = jax.jit(fedround.make_round_fn(
-        lambda tree, mb: jnp.sum(tree["w"]["a"] ** 2), meta, fed, spec_ok))
-    out = fn_ok(flatP, fedround.init_server(flatP), {},
+        lambda bb, tree, mb: jnp.sum(tree["w"]["a"] ** 2), meta, fed,
+        spec_ok))
+    out = fn_ok({}, flatP, fedround.init_server(flatP), {},
                 {"x": jnp.zeros((2, 1, 2, 1))}, jax.random.key(0))
     assert np.isfinite(float(out[3]["loss"]))
 
@@ -314,14 +316,17 @@ def test_dp_fallback_key_rotates_per_round():
                           dp_clip=1.0, dp_noise=0.5)
     flatP = meta.flatten(tree)
     batches = {"x": jnp.zeros((2, 1, 2, 1))}
-    kw = dict(loss_of=lambda t, mb: jnp.sum(t["w"]["a"] ** 2), meta=meta,
-              fed=fed, strategy=st.StrategySpec(kind="lora"))
+    kw = dict(loss_of=lambda bb, t, mb: jnp.sum(t["w"]["a"] ** 2),
+              meta=meta, fed=fed, strategy=st.StrategySpec(kind="lora"))
     server0 = fedround.init_server(flatP)
-    out_a = fedround.federated_round(flatP, server0, {}, batches, None, **kw)
-    out_b = fedround.federated_round(flatP, server0, {}, batches, None, **kw)
+    out_a = fedround.federated_round({}, flatP, server0, {}, batches, None,
+                                     **kw)
+    out_b = fedround.federated_round({}, flatP, server0, {}, batches, None,
+                                     **kw)
     # deterministic at a fixed round...
     assert jnp.array_equal(out_a[0], out_b[0])
     # ...but a different round index must draw different noise
     server1 = dict(server0, round=jnp.asarray(1, jnp.int32))
-    out_c = fedround.federated_round(flatP, server1, {}, batches, None, **kw)
+    out_c = fedround.federated_round({}, flatP, server1, {}, batches, None,
+                                     **kw)
     assert not jnp.array_equal(out_a[0], out_c[0])

@@ -278,18 +278,19 @@ def _quadratic_round(kind="flasc", rounds=30, **kw):
     fed = FederatedConfig(n_clients=4, local_batch=2, local_steps=1,
                           client_lr=0.2, server_lr=0.05, **kw)
 
-    def loss_of(tree, mb):
-        return jnp.mean((tree["w"]["a"] - target) ** 2) + jnp.mean(tree["w"]["b"] ** 2)
+    def loss_of(bb, tree, mb):
+        return jnp.mean((tree["w"]["a"] - bb) ** 2) + jnp.mean(tree["w"]["b"] ** 2)
 
     spec = st.StrategySpec(kind=kind, density_down=0.5, density_up=0.5)
     flatP = meta.flatten(trainable)
     server = fedround.init_server(flatP)
-    sstate = st.init_strategy_state(spec, meta.p_len)
+    sstate = st.resolve(spec).init_state(meta.p_len)
     fn = jax.jit(fedround.make_round_fn(loss_of, meta, fed, spec))
     batch = {"x": jnp.zeros((4, 1, 2, 1))}
     losses = []
     for r in range(rounds):
-        flatP, server, sstate, m = fn(flatP, server, sstate, batch, jax.random.key(r))
+        flatP, server, sstate, m = fn(target, flatP, server, sstate, batch,
+                                      jax.random.key(r))
         losses.append(float(m["loss"]))
     return losses, flatP, meta
 
@@ -312,16 +313,16 @@ def test_round_metrics_densities():
     fed = FederatedConfig(n_clients=4, local_batch=2, local_steps=1,
                           client_lr=0.1, server_lr=0.05)
 
-    def loss_of(tree, mb):
-        return jnp.mean((tree["w"]["a"] - target) ** 2)
+    def loss_of(bb, tree, mb):
+        return jnp.mean((tree["w"]["a"] - bb) ** 2)
 
     spec = st.StrategySpec(kind="flasc", density_down=0.25, density_up=0.125)
     flatP = meta.flatten(trainable)
     server = fedround.init_server(flatP)
-    sstate = st.init_strategy_state(spec, meta.p_len)
+    sstate = st.resolve(spec).init_state(meta.p_len)
     fn = fedround.make_round_fn(loss_of, meta, fed, spec)
-    _, _, _, m = jax.jit(fn)(flatP, server, sstate, {"x": jnp.zeros((4, 1, 2, 1))},
-                             jax.random.key(0))
+    _, _, _, m = jax.jit(fn)(target, flatP, server, sstate,
+                             {"x": jnp.zeros((4, 1, 2, 1))}, jax.random.key(0))
     # download: ~25% of 512 entries; ties possible at equal magnitudes
     assert float(m["down_nnz"]) >= 0.25 * meta.p_len
     # upload: each client <= ceil(12.5%) of entries, only a-entries nonzero
@@ -389,20 +390,21 @@ def test_flasc_ef_residual_invariant():
                           client_lr=0.1, server_lr=0.01)
     spec = st.StrategySpec(kind="flasc_ef", density_down=0.25, density_up=0.5)
 
-    def loss_of(tree, mb):
+    def loss_of(bb, tree, mb):
         return jnp.mean(tree["w"]["a"] ** 2)
 
     flatP = meta.flatten(trainable)
     server = fedround.init_server(flatP)
-    sstate = st.init_strategy_state(spec, meta.p_len)
+    sstate = st.resolve(spec).init_state(meta.p_len)
     fn = jax.jit(fedround.make_round_fn(loss_of, meta, fed, spec))
     batch = {"x": jnp.zeros((2, 1, 2, 1))}
-    P1, server, sstate, m = fn(flatP, server, sstate, batch, jax.random.key(0))
+    P1, server, sstate, m = fn({}, flatP, server, sstate, batch,
+                               jax.random.key(0))
     # residual supported exactly on the (1 - d_down) unsent entries
     assert int(jnp.sum(sstate["e"] != 0)) == meta.p_len - meta.p_len // 4
     assert float(m["up_nnz"]) <= 2 * (0.5 * meta.p_len + 1)
     # next round consumes the residual without error
-    P2, _, sstate2, m2 = fn(P1, server, sstate, batch, jax.random.key(1))
+    P2, _, sstate2, m2 = fn({}, P1, server, sstate, batch, jax.random.key(1))
     assert jnp.isfinite(m2["loss"])
 
 
@@ -442,13 +444,13 @@ def test_fedavg_server_rule():
                           server_lr=1.0, server_opt="sgd")
     spec = st.StrategySpec(kind="lora")
 
-    def loss_of(tree, mb):
+    def loss_of(bb, tree, mb):
         return jnp.sum(tree["w"]["a"]) + jnp.sum(tree["w"]["b"])   # grad = 1
 
     flatP = meta.flatten(trainable)
     server = fedround.init_server(flatP)
     fn = jax.jit(fedround.make_round_fn(loss_of, meta, fed, spec))
-    P1, _, _, _ = fn(flatP, server, {}, {"x": jnp.zeros((2, 1, 2, 1))},
+    P1, _, _, _ = fn({}, flatP, server, {}, {"x": jnp.zeros((2, 1, 2, 1))},
                      jax.random.key(0))
     # delta = lr_client * grad = 0.5 everywhere; FedAvg: P - 1.0*0.5
     np.testing.assert_allclose(np.asarray(P1), 0.5, rtol=1e-6)

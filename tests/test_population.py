@@ -337,6 +337,56 @@ def test_async_engine_rejects_population_bundle(task):
         exp.run()
 
 
+@pytest.mark.fast
+@pytest.mark.parametrize("engine", ["sim", "sharded"])
+def test_zero_client_mu_round_equals_plain_round_bit_for_bit(engine):
+    """One round factory serves plain and population rounds: a cohort
+    whose persistent momentum rows are all zeros computes the plain
+    round's weights, server state, strategy state and per-client losses
+    bit for bit, on SimEngine and on a (1, 1) ShardedEngine."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import fedround
+    from repro.core import strategies as st
+    from repro.models.config import FederatedConfig
+    n = 4
+    tree = {"a": 0.1 * jax.random.normal(jax.random.key(1), (8, 4)),
+            "b": 0.05 * jax.random.normal(jax.random.key(2), (4, 8))}
+    meta = fedround.FlatMeta.of(tree)
+    params = {"w": jax.random.normal(jax.random.key(3), (8, 8))}
+
+    def loss_of(bb, t, mb):
+        return jnp.mean((mb["x"] @ (bb["w"] + t["a"] @ t["b"])) ** 2)
+
+    fed = FederatedConfig(n_clients=n, local_batch=2, local_steps=2,
+                          client_lr=0.1)
+    strat = st.resolve(st.StrategySpec(kind="flasc_ef", density_down=0.5,
+                                       density_up=0.5, quant_bits_up=4))
+    plan = eng.RoundTask(loss_of, meta, fed, strat, params=params)
+    e = eng.resolve_engine(engine)
+    batch = {"x": jax.random.normal(jax.random.key(4), (n, 2, 2, 8))}
+
+    def run(*client_mu):
+        # fresh state every call: the sharded step donates it
+        flatP = meta.flatten(tree)
+        out = e.compile(plan)(
+            *e._step_params(plan), flatP, fedround.init_server(flatP),
+            strat.init_state(meta.p_len), batch, jax.random.key(5),
+            *client_mu)
+        return jax.tree.map(np.asarray, out)
+
+    plain = run()
+    zero = run(jnp.zeros((n, meta.p_len), jnp.float32))
+    assert "client_mu" not in plain[3] and zero[3]["client_mu"].shape == (
+        n, meta.p_len)
+    for a, b in zip(jax.tree.leaves(plain[:3]), jax.tree.leaves(zero[:3])):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(plain[3]["loss_clients"],
+                                  zero[3]["loss_clients"])
+    # the finals are real momentum, not the zeros that went in
+    assert np.any(zero[3]["client_mu"] != 0)
+
+
 # ---------------------------------------------------------------------------
 # hierarchical two-level aggregation
 # ---------------------------------------------------------------------------

@@ -55,17 +55,17 @@ def test_round_with_quantization_converges():
                            quant_bits_down=8, quant_bits_up=8)
     target = jax.random.normal(jax.random.key(1), (16, 4))
 
-    def loss_of(tree, mb):
-        return jnp.mean((tree["w"]["a"] - target) ** 2)
+    def loss_of(bb, tree, mb):
+        return jnp.mean((tree["w"]["a"] - bb) ** 2)
 
     flatP = meta.flatten(trainable)
     server = fedround.init_server(flatP)
-    sstate = st.init_strategy_state(spec, meta.p_len)
+    sstate = st.resolve(spec).init_state(meta.p_len)
     fn = jax.jit(fedround.make_round_fn(loss_of, meta, fed, spec))
     batch = {"x": jnp.zeros((4, 1, 2, 1))}
     losses = []
     for r in range(30):
-        flatP, server, sstate, m = fn(flatP, server, sstate, batch,
+        flatP, server, sstate, m = fn(target, flatP, server, sstate, batch,
                                       jax.random.key(r))
         losses.append(float(m["loss"]))
     assert losses[-1] < 0.5 * losses[0]

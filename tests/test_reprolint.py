@@ -485,7 +485,7 @@ def test_params_closure_fires():
     found = lint(bad, "params-closure", rel="src/repro/federated/fake.py")
     assert len(found) == 1
     assert "`round_fn` closes over `params`" in found[0].message
-    assert "with_params=True" in found[0].message
+    assert "pfn(params, flatP, ...)" in found[0].message
 
 
 def test_params_closure_silent_on_explicit_argument_and_scope():

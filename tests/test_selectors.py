@@ -271,7 +271,7 @@ def _tiny_problem():
     fed = FederatedConfig(n_clients=4, local_batch=2, local_steps=2,
                           client_lr=0.1, client_momentum=0.0, server_lr=0.1)
 
-    def loss_of(tree, mb):
+    def loss_of(bb, tree, mb):
         flat = jnp.concatenate([tree["lora"]["l"]["a"].reshape(-1),
                                 tree["lora"]["l"]["b"].reshape(-1)])
         return jnp.sum((flat - jnp.mean(mb["t"])) ** 2)
@@ -285,7 +285,7 @@ def _tiny_problem():
 def _one_round(spec, meta, fed, loss_of, batches, flat0):
     strat = st.resolve(spec)
     return fedround.federated_round(
-        flat0, fedround.init_server(flat0), strat.init_state(meta.p_len),
+        {}, flat0, fedround.init_server(flat0), strat.init_state(meta.p_len),
         batches, None, loss_of=loss_of, meta=meta, fed=fed, strategy=strat)
 
 

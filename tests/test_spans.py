@@ -159,14 +159,14 @@ def round_hlo():
                                 with_rank_map=False)
     w = jnp.linspace(-1.0, 1.0, 16 * p_len).reshape(16, p_len)
 
-    def loss_of(tree, mb):
-        return jnp.mean((mb["x"] @ (w * tree["a"])) ** 2)
+    def loss_of(bb, tree, mb):
+        return jnp.mean((mb["x"] @ (bb * tree["a"])) ** 2)
 
     fed = FederatedConfig(n_clients=4, local_batch=2, local_steps=1)
     fn = fedround.make_round_fn(loss_of, meta, fed, FLASC)
     flatP = jnp.zeros((p_len,), jnp.float32)
     batches = {"x": jnp.ones((4, 1, 2, 16), jnp.float32)}
-    args = (flatP, fedround.init_server(flatP),
+    args = (w, flatP, fedround.init_server(flatP),
             st.resolve(FLASC).init_state(p_len), batches, jax.random.key(0))
     return jax.jit(fn).lower(*args).compile().as_text()
 

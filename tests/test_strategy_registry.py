@@ -4,6 +4,7 @@ new `Strategy` registry versus the seed if/elif implementation (frozen in
 `legacy_seed.py`), plus the heterogeneous-upload-quantization regression
 the redesign fixes."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -47,16 +48,17 @@ def _toy():
     fed = FederatedConfig(n_clients=N_CLIENTS, local_batch=2, local_steps=1,
                           client_lr=0.2, server_lr=0.05)
 
-    def loss_of(tree, mb):
-        return (jnp.mean((tree["w"]["a"] - target) ** 2)
+    def loss_of(bb, tree, mb):
+        return (jnp.mean((tree["w"]["a"] - bb) ** 2)
                 + jnp.mean(tree["w"]["b"] ** 2))
 
     batch = {"x": jnp.zeros((N_CLIENTS, 1, 2, 1))}
-    return meta, fed, loss_of, batch, meta.flatten(trainable)
+    return meta, fed, loss_of, target, batch, meta.flatten(trainable)
 
 
-def _drive(round_fn, init_state_fn, meta, fed, loss_of, batch, flatP):
-    """Run ROUNDS rounds; returns (flatP, sstate, per-round metrics, ledger)."""
+def _drive(round_fn, init_state_fn, meta, fed, batch, flatP):
+    """Run ROUNDS rounds of `round_fn(flatP, server, sstate, batch, rng)`;
+    returns (flatP, sstate, per-round metrics, ledger)."""
     server = fedround.init_server(flatP)
     sstate = init_state_fn(meta.p_len)
     ledger = CommLedger(total_params=meta.p_len)
@@ -73,23 +75,27 @@ def _drive(round_fn, init_state_fn, meta, fed, loss_of, batch, flatP):
 @pytest.mark.parametrize("kind,kw", CASES,
                          ids=[f"{k}-{i}" for i, (k, _) in enumerate(CASES)])
 def test_registry_matches_seed_bit_identical(kind, kw):
-    meta, fed, loss_of, batch, flatP0 = _toy()
+    meta, fed, loss_of, backbone, batch, flatP0 = _toy()
     spec = st.StrategySpec(kind=kind, density_down=0.5, density_up=0.5, **kw)
     strat = st.resolve(spec)
 
-    new_fn = jax.jit(fedround.make_round_fn(loss_of, meta, fed, strat))
+    new_fn = functools.partial(
+        jax.jit(fedround.make_round_fn(loss_of, meta, fed, strat)), backbone)
 
+    # the frozen seed takes the two-argument loss that closes over the
+    # backbone
     def legacy_fn(flatP, server, sstate, cb, rng):
-        return legacy_seed.federated_round(flatP, server, sstate, cb, rng,
-                                           loss_of=loss_of, meta=meta, fed=fed,
-                                           spec=spec)
+        return legacy_seed.federated_round(
+            flatP, server, sstate, cb, rng,
+            loss_of=functools.partial(loss_of, backbone), meta=meta, fed=fed,
+            spec=spec)
     legacy_fn = jax.jit(legacy_fn)
 
     P_new, ss_new, hist_new, led_new = _drive(
-        new_fn, strat.init_state, meta, fed, loss_of, batch, flatP0)
+        new_fn, strat.init_state, meta, fed, batch, flatP0)
     P_old, ss_old, hist_old, led_old = _drive(
         legacy_fn, lambda p: legacy_seed.init_strategy_state(spec, p),
-        meta, fed, loss_of, batch, flatP0)
+        meta, fed, batch, flatP0)
 
     # round outputs: final weights bit for bit; nnz counts (the ledger
     # inputs) bit for bit.  The loss/grad_norm *diagnostics* are reductions
@@ -120,7 +126,7 @@ def test_registry_matches_seed_bit_identical(kind, kw):
 def test_client_plans_match_seed_masks(kind, kw):
     """Hook-level equivalence: download mask + per-client (m_down, m_train,
     upload rule) match the seed dispatch for every client slot."""
-    meta, _, _, _, flatP = _toy()
+    meta, _, _, _, _, flatP = _toy()
     spec = st.StrategySpec(kind=kind, density_down=0.5, density_up=0.5, **kw)
     strat = st.resolve(spec)
     sstate = strat.init_state(meta.p_len)
@@ -152,29 +158,39 @@ def test_het_upload_quantization_applied():
     quant_bits_up/quant_key to `_client_update`, so per-client-density runs
     silently skipped upload quantization.  The single vmapped path must
     quantize het uploads; the frozen seed demonstrably does not."""
-    meta, fed, loss_of, batch, flatP0 = _toy()
+    meta, fed, loss_of, backbone, batch, flatP0 = _toy()
     het = dict(client_densities=(0.25, 0.5, 0.75, 1.0))
 
+    def new_round(bb, flatP, server, sstate, cb, rng, spec):
+        return fedround.federated_round(bb, flatP, server, sstate, cb, rng,
+                                        loss_of=loss_of, meta=meta, fed=fed,
+                                        strategy=spec)
+
+    def legacy_round(bb, flatP, server, sstate, cb, rng, spec):
+        return legacy_seed.federated_round(
+            flatP, server, sstate, cb, rng,
+            loss_of=functools.partial(loss_of, bb), meta=meta, fed=fed,
+            spec=spec)
+
     def final_weights(round_impl, spec):
-        def fn(flatP, server, sstate, cb, rng):
-            return round_impl(flatP, server, sstate, cb, rng,
-                              loss_of=loss_of, meta=meta, fed=fed, spec=spec)
-        P, _, _, _ = _drive(jax.jit(fn),
+        def fn(bb, flatP, server, sstate, cb, rng):
+            return round_impl(bb, flatP, server, sstate, cb, rng, spec)
+        P, _, _, _ = _drive(functools.partial(jax.jit(fn), backbone),
                             lambda p: legacy_seed.init_strategy_state(spec, p),
-                            meta, fed, loss_of, batch, flatP0)
+                            meta, fed, batch, flatP0)
         return np.asarray(P)
 
     spec_q = st.StrategySpec(kind="flasc", density_down=0.5, quant_bits_up=2,
                              **het)
     spec_nq = st.StrategySpec(kind="flasc", density_down=0.5, **het)
 
-    new_q = final_weights(fedround.federated_round, spec_q)
-    new_nq = final_weights(fedround.federated_round, spec_nq)
+    new_q = final_weights(new_round, spec_q)
+    new_nq = final_weights(new_round, spec_nq)
     assert not np.array_equal(new_q, new_nq), \
         "2-bit upload quantization must change heterogeneous round outputs"
 
-    legacy_q = final_weights(legacy_seed.federated_round, spec_q)
-    legacy_nq = final_weights(legacy_seed.federated_round, spec_nq)
+    legacy_q = final_weights(legacy_round, spec_q)
+    legacy_nq = final_weights(legacy_round, spec_nq)
     assert np.array_equal(legacy_q, legacy_nq), \
         "seed het branch ignored quant_bits_up (the bug this PR fixes)"
 
@@ -182,15 +198,16 @@ def test_het_upload_quantization_applied():
 def test_het_download_quantization_applied():
     """Same regression for the download direction: het runs must quantize
     the per-client download message when quant_bits_down is set."""
-    meta, fed, loss_of, batch, flatP0 = _toy()
+    meta, fed, loss_of, backbone, batch, flatP0 = _toy()
     spec_q = st.StrategySpec(kind="hetlora", hetlora_ranks=(1, 2, 3, 4),
                              quant_bits_down=2)
     spec_nq = st.StrategySpec(kind="hetlora", hetlora_ranks=(1, 2, 3, 4))
 
     def final_weights(spec):
         fn = jax.jit(fedround.make_round_fn(loss_of, meta, fed, spec))
-        P, _, _, _ = _drive(fn, st.resolve(spec).init_state, meta, fed,
-                            loss_of, batch, flatP0)
+        P, _, _, _ = _drive(functools.partial(fn, backbone),
+                            st.resolve(spec).init_state, meta, fed, batch,
+                            flatP0)
         return np.asarray(P)
 
     assert not np.array_equal(final_weights(spec_q), final_weights(spec_nq))

@@ -202,7 +202,7 @@ def _tiny_problem():
     fed = FederatedConfig(n_clients=4, local_batch=2, local_steps=2,
                           client_lr=0.1, client_momentum=0.0, server_lr=0.1)
 
-    def loss_of(tree, mb):
+    def loss_of(bb, tree, mb):
         flat = jnp.concatenate([tree["lora"]["l"]["a"].reshape(-1),
                                 tree["lora"]["l"]["b"].reshape(-1)])
         return jnp.sum((flat - jnp.mean(mb["t"])) ** 2)
@@ -228,7 +228,8 @@ def test_round_ledger_matches_closed_form(mode):
                           up_value_bytes=vb, down_dense=dense, up_dense=dense)
     rounds = 3
     for i in range(rounds):
-        flatP, server, sstate, m = fn(flatP, server, sstate, batches, None)
+        flatP, server, sstate, m = fn({}, flatP, server, sstate, batches,
+                                      None)
         led.record_round(fed.n_clients, float(m["down_nnz"]),
                          float(m["up_nnz"]),
                          down_per_message=[float(v) for v in

@@ -18,9 +18,9 @@ trees (core/, federated/, launch/) that *closes over* the backbone
 `params` instead of taking it as an argument.  A closed-over backbone is
 baked into the trace as a constant: it can't be given an in_shardings
 entry (so FSDP/TP storage sharding silently degrades to replication),
-it escapes the donation audit, and every re-trace re-embeds it.  The
-sharded-params round path threads it explicitly
-(`fedround.make_round_fn(..., with_params=True)`).
+it escapes the donation audit, and every re-trace re-embeds it.  Every
+round takes it explicitly, as its leading argument
+(`fedround.make_round_fn` returns `pfn(params, flatP, ...)`).
 """
 from __future__ import annotations
 
@@ -185,5 +185,5 @@ class ParamsClosure(Rule):
                     "into the trace as a constant: no in_shardings entry "
                     "(FSDP/TP storage sharding degrades to replication), "
                     "invisible to the donation audit, re-embedded on "
-                    "every re-trace; thread it explicitly "
-                    "(fedround.make_round_fn(..., with_params=True))")
+                    "every re-trace; take it as the leading argument, "
+                    "like fedround.make_round_fn's pfn(params, flatP, ...)")
